@@ -810,7 +810,7 @@ def t13_fused(quick=False):
     amortizes — with block_until_ready outside it (the per-step loop is
     windowed at 8 dispatches so the CPU client's in-flight backpressure
     never turns dispatch synchronous inside a timed region); both sides
-    are timed without donation because on jax 0.4.x CPU an execution
+    are timed without donation because on the CPU backend an execution
     whose input buffers are actually CONSUMED by donation runs
     synchronously (the
     production donated path is timed separately as wall clock per
@@ -1377,8 +1377,11 @@ def t16_hier(quick=False):
                    jax.ShapeDtypeStruct((2,), jnp.uint32))
         print("lowered 1")
     """)
+    # host-device lowering only: the child must not reach for the chip
+    # this (JAX-holding) parent process may own
     proc = subprocess.run([sys.executable, "-c", script],
-                          capture_output=True, text=True, timeout=600)
+                          capture_output=True, text=True, timeout=600,
+                          env={**os.environ, "JAX_PLATFORMS": "cpu"})
     assert proc.returncode == 0, proc.stderr[-3000:]
     out["dryrun_1024_nodes_512_devices"] = "lowered 1" in proc.stdout
     assert out["dryrun_1024_nodes_512_devices"]
@@ -1477,6 +1480,8 @@ def main():
                          "summary.json (one row per t8-t16 headline "
                          "metric); runs after any tables selected")
     args = ap.parse_args()
+    from repro.launch.cache import use_compile_cache
+    use_compile_cache()
     names = args.only.split(",") if args.only else list(TABLES)
     if args.summary and args.only is None:
         names = []                     # bare --summary: consolidate only

@@ -34,7 +34,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.compat import shard_map_compat
 from repro.quant.codecs import LatticeCodec, WireCodec, make_codec
 from repro.quant.schemes import ModularQuantConfig, payload_bytes
 
@@ -158,25 +157,55 @@ def build_flat_layout(tree, *, block: int = DEFAULT_BLOCK,
     return layout
 
 
-def pack_flat(layout: BucketLayout, tree) -> jax.Array:
-    """Un-stacked pytree -> [n_padded] fp32 vector (zeros-prefill + one
-    slice write per leaf, same idiom as `pack`)."""
-    leaves = jax.tree.leaves(tree)
-    buf = jnp.zeros((layout.n_padded,), jnp.float32)
-    for x, off, size in zip(leaves, layout.offsets, layout.sizes):
-        buf = buf.at[off:off + size].set(
-            x.reshape(size).astype(jnp.float32))
+def _leaf_rows(x, seg: int, block: int):
+    """One leaf as its zero-padded [seg // block, block] fp32 rows."""
+    if x.size == seg and x.ndim and x.shape[-1] % block == 0:
+        return x.reshape(-1, block).astype(jnp.float32)
+    flat = jnp.pad(x.reshape(-1), (0, seg - x.size))
+    return flat.reshape(-1, block).astype(jnp.float32)
+
+
+def pack_rows(layout: BucketLayout, tree) -> jax.Array:
+    """Un-stacked pytree -> [n_padded // block, block] fp32 rows: the flat
+    layout as the kernels tile it (zeros-prefill + one row-slice write per
+    leaf). Built row-wise, never through a 1-D vector: on a TPU the
+    relayout from a leaf to a 1-D vector and on to kernel rows compiles to
+    code that grows with the model (over a minute of every transformer-wmt
+    superstep's compile)."""
+    buf = jnp.zeros((layout.n_padded // layout.block, layout.block),
+                    jnp.float32)
+    for x, off, seg in zip(jax.tree.leaves(tree), layout.offsets,
+                           layout.seg_sizes):
+        buf = jax.lax.dynamic_update_slice_in_dim(
+            buf, _leaf_rows(x, seg, layout.block), off // layout.block, 0)
     return buf
+
+
+def unpack_rows(layout: BucketLayout, buf: jax.Array):
+    """[n_padded // block, block] rows -> un-stacked pytree (original
+    dtypes); the inverse of `pack_rows`."""
+    b = layout.block
+    outs = []
+    for off, size, seg, shp, dt in zip(layout.offsets, layout.sizes,
+                                       layout.seg_sizes, layout.shapes,
+                                       layout.dtypes):
+        rows = jax.lax.slice_in_dim(buf, off // b, (off + seg) // b, axis=0)
+        if size == seg and shp and shp[-1] % b == 0:
+            leaf = rows.reshape(shp)
+        else:
+            leaf = rows.reshape(-1)[:size].reshape(shp)
+        outs.append(leaf.astype(dt))
+    return jax.tree.unflatten(layout.treedef, outs)
+
+
+def pack_flat(layout: BucketLayout, tree) -> jax.Array:
+    """Un-stacked pytree -> [n_padded] fp32 vector."""
+    return pack_rows(layout, tree).reshape(-1)
 
 
 def unpack_flat(layout: BucketLayout, buf: jax.Array):
     """[n_padded] fp32 vector -> un-stacked pytree (original dtypes)."""
-    outs = []
-    for off, size, shp, dt in zip(layout.offsets, layout.sizes,
-                                  layout.shapes, layout.dtypes):
-        seg = jax.lax.slice_in_dim(buf, off, off + size, axis=0)
-        outs.append(seg.astype(dt).reshape(shp))
-    return jax.tree.unflatten(layout.treedef, outs)
+    return unpack_rows(layout, buf.reshape(-1, layout.block))
 
 
 def pack(layout: BucketLayout, tree) -> jax.Array:
@@ -208,8 +237,17 @@ def unpack(layout: BucketLayout, buf: jax.Array):
 # ---------------------------------------------------------------------------
 
 
+def row_mask(matched, rows_per_node: int):
+    """Per-node bool [n_nodes] -> the kernels' per-row mask column
+    [n_nodes * rows_per_node, 1], as a lookup of each row's node. (As
+    `jnp.repeat` it is a broadcast plus a reshape, which the TPU compiler
+    turns into a relayout whose code grows with the model.)"""
+    node_of_row = jnp.arange(matched.shape[0] * rows_per_node) // rows_per_node
+    return matched[node_of_row][:, None]
+
+
 def gossip_flat_exact(buf, perm, matched=None):
-    """(buf + buf[perm]) / 2 — ONE gather over one tensor. With
+    """(buf + buf[perm]) / 2 — ONE row permute over one tensor. With
     `matched=None` no mask pass is needed: `perm` is an involution with
     fixed points at unmatched nodes, and (x + x) * 0.5 == x bitwise for
     every finite float. A non-None `matched` (bool [n_nodes]) additionally
@@ -218,7 +256,7 @@ def gossip_flat_exact(buf, perm, matched=None):
     bin (pool/static-matching transports; sched/bridge.py). For a full
     mask the `where` selects bitwise-identical values, so the masked path
     reproduces the unmasked trajectory exactly."""
-    avg = (buf + buf[perm]) * 0.5
+    avg = (buf + permute_rows(buf, perm, buf.shape[0])) * 0.5
     if matched is None:
         return avg
     return jnp.where(matched[:, None], avg, buf)
@@ -259,7 +297,7 @@ def gossip_flat_coded(codec: WireCodec, buf, prev_buf, perm, matched, rng, *,
         wire = codec.encode(buf, prev_buf, rng, tile_rows=tile_rows,
                             backend=backend)
     wire_p = tuple(permute_rows(w, perm, n_nodes) for w in wire)
-    m_rows = jnp.repeat(matched, rpn)
+    m_rows = row_mask(matched, rpn)
     out = codec.decode_avg(wire_p, buf, m_rows, tile_rows=tile_rows,
                            backend=backend)
     return out, new_residual
@@ -324,13 +362,17 @@ def pairs_from_perm(perm_arr):
 
 
 def permute_rows(x, perm, n_nodes: int):
-    """Gather-permute node-grouped rows: x is [n_nodes, ...] or
+    """`x` with its node row groups permuted: x is [n_nodes, ...] or
     [n_nodes * r, ...] with node-contiguous row groups (the (q, s) kernel
-    layout packs rows_per_node consecutive rows per node)."""
-    if x.shape[0] == n_nodes:
-        return x[perm]
+    layout packs rows_per_node consecutive rows per node). One dynamic
+    slice per node, bitwise the gather `x.reshape(n, r, ...)[perm]`: the
+    TPU compiler expands a gather of whole model rows into one copy per
+    chunk of rows, so its code size and compile time grow with the model,
+    and the reshape to [n, r, ...] is itself a relayout."""
     r = x.shape[0] // n_nodes
-    return x.reshape((n_nodes, r) + x.shape[1:])[perm].reshape(x.shape)
+    return jnp.concatenate(
+        [jax.lax.dynamic_slice_in_dim(x, perm[i] * r, r, axis=0)
+         for i in range(n_nodes)], axis=0)
 
 
 def permute_payload_ppermute(payload, mesh, node_axes, pairs, n_nodes: int):
@@ -354,7 +396,8 @@ def permute_payload_ppermute(payload, mesh, node_axes, pairs, n_nodes: int):
     def f(*xs):
         return tuple(jax.lax.ppermute(x, axis, full_pairs) for x in xs)
 
-    fn = shard_map_compat(f, mesh, in_specs=specs, out_specs=specs)
+    fn = jax.shard_map(f, mesh=mesh, in_specs=specs, out_specs=specs,
+                       check_vma=False)
     return fn(*payload)
 
 
@@ -431,25 +474,19 @@ def gossip_flat_ppermute(buf, mesh, node_axes, pairs, *,
         # ONE collective per codec wire group (q+s lattice; v bf16; ...)
         wire_p = tuple(jax.lax.ppermute(w, axis, full_pairs) for w in wire)
         m = _local_mask(idx, mk)
-        m_rows = jnp.broadcast_to(m, (wire[0].shape[0],))
+        m_rows = jnp.broadcast_to(m, (wire[0].shape[0], 1))
         return codec.decode_avg(wire_p, x, m_rows, tile_rows=tile_rows,
                                 backend=backend)
 
     if codec is None:
-        if mask is None:
-            fn = shard_map_compat(exact, mesh, in_specs=(spec,),
-                                  out_specs=spec)
-            return fn(buf)
-        fn = shard_map_compat(exact, mesh, in_specs=(spec, P()),
-                              out_specs=spec)
-        return fn(buf, mask)
-    if mask is None:
-        fn = shard_map_compat(quantized, mesh, in_specs=(spec, spec, P()),
-                              out_specs=spec)
-        return fn(buf, prev_buf, rng)
-    fn = shard_map_compat(quantized, mesh, in_specs=(spec, spec, P(), P()),
-                          out_specs=spec)
-    return fn(buf, prev_buf, rng, mask)
+        f, args, in_specs = exact, (buf,), (spec,)
+    else:
+        f, args, in_specs = quantized, (buf, prev_buf, rng), (spec, spec, P())
+    if mask is not None:
+        args, in_specs = args + (mask,), in_specs + (P(),)
+    fn = jax.shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=spec,
+                       check_vma=False)
+    return fn(*args)
 
 
 def gossip_flat_ppermute_pool(buf, mesh, node_axes, pool, pool_idx, *,

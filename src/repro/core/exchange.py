@@ -45,7 +45,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.compat import shard_map_compat
 from repro.core import bucket as B
 from repro.quant.codecs import LatticeCodec, WireCodec, make_codec
 from repro.quant.schemes import (
@@ -174,14 +173,14 @@ def gossip_ppermute(params, param_specs, mesh, node_axes, pairs,
     out = []
     for x, spec, pv, key in zip(leaves, specs, prev_leaves, keys):
         if quant is not None:
-            fn = shard_map_compat(per_leaf(spec), mesh,
-                                  in_specs=(spec, spec, P()),
-                                  out_specs=spec)
+            fn = jax.shard_map(per_leaf(spec), mesh=mesh,
+                               in_specs=(spec, spec, P()), out_specs=spec,
+                               check_vma=False)
             out.append(fn(x, pv, key))
         else:
-            fn = shard_map_compat(
-                lambda x_: per_leaf(spec)(x_, None, None), mesh,
-                in_specs=(spec,), out_specs=spec)
+            fn = jax.shard_map(
+                lambda x_: per_leaf(spec)(x_, None, None), mesh=mesh,
+                in_specs=(spec,), out_specs=spec, check_vma=False)
             out.append(fn(x))
     return jax.tree.unflatten(tdef, out)
 
@@ -500,14 +499,16 @@ class GossipTransport:
         return jnp.zeros((layout.n_nodes, layout.n_padded), jnp.float32)
 
 
-def transport_from_config(scfg, graph, seed: int = 0, param_probe=None
-                          ) -> GossipTransport:
+def transport_from_config(scfg, graph, seed: int = 0, param_probe=None,
+                          devices=None) -> GossipTransport:
     """Standard driver plumbing: a transport for `scfg.gossip_impl` on the
-    single-host training mesh (one shard: the collective degenerates to a
-    local permute; the same wiring carries a real node mesh on multi-device
-    runs). `param_probe` is an abstract single-node param tree, only needed
-    for the per-leaf legacy shard_map modes, which shard each leaf by its
-    own replicated spec.
+    swarm's node mesh (launch/mesh.py `node_mesh`). On one device the mesh
+    has one shard and the collective degenerates to a local permute; over
+    several devices (`devices`, default every device the process sees)
+    each holds one node and the shard_map transports permute across them.
+    `param_probe` is an abstract single-node param tree, only needed for
+    the per-leaf legacy shard_map modes, which shard each leaf by its own
+    node-leading spec.
 
     The wire format comes from `scfg.codec` (+ `scfg.quant` seeding the
     lattice family). Every supported codec runs the FLAT transport — the
@@ -519,14 +520,19 @@ def transport_from_config(scfg, graph, seed: int = 0, param_probe=None
     quant = getattr(scfg, "quant", None)
     codec = make_codec(getattr(scfg, "codec", None), quant)
     kw = dict(quant=quant, codec=codec)
+    from repro.launch.mesh import auto_mesh, node_mesh
+    mesh = node_mesh(scfg.n_nodes, devices)
+    if mesh is not None:
+        kw.update(mesh=mesh, node_axes=("node",))
     if base != "gather":
         from jax.sharding import PartitionSpec as P
 
-        from repro.compat import make_mesh_compat
-        kw.update(mesh=make_mesh_compat((1,), ("node",)), node_axes=())
+        if mesh is None:
+            kw.update(mesh=auto_mesh((1,), ("node",)), node_axes=())
+        node = "node" if mesh is not None else None
         if param_probe is not None:
             kw["param_specs"] = jax.tree.map(
-                lambda x: P(*((None,) * (x.ndim + 1))), param_probe)
+                lambda x: P(node, *((None,) * x.ndim)), param_probe)
         if base == "ppermute":
             kw["static_pairs"] = B.pairs_from_perm(
                 static_ppermute_matching(graph, seed))

@@ -24,7 +24,7 @@ Donation: the chunk jit donates (state, key) — params/opt/prev/residual/
 inflight update in place across the boundary instead of double-buffering
 the packed model. Callers MUST rebind both from the return value; the
 donated inputs are dead after the call (tests/test_scan_driver.py asserts
-the aliasing actually happens via repro.compat.donation_alias_count).
+the aliasing actually happens in the lowered module).
 
 Composition with compress_state (DESIGN.md §Hierarchy): when the comm
 copy lives codec-encoded, `state.prev` is a tuple of wire-word arrays —

@@ -324,10 +324,23 @@ def make_swarm_step(cfg: SwarmConfig, loss_fn: Callable, opt_update: Callable,
     # one node's H local SGD steps (no collectives) — THE shared loop
     # (core/exchange.py), also used by the h-consuming baselines
     local_steps = make_local_steps(loss_fn, opt_update, h_max)
+    vmapped = jax.vmap(local_steps, in_axes=(0, 0, 0, 0, None))
+    if tr.node_axes and tr.mesh is not None and \
+            set(tr.mesh.axis_names) == set(tr.node_axes):
+        # a pure node mesh (one node per device, launch/mesh.py node_mesh):
+        # each device runs its own node's local steps. Under plain GSPMD
+        # the optimizer's Pallas kernel (a custom call the partitioner
+        # cannot split) would be gathered and run for every node everywhere
+        from jax.sharding import PartitionSpec as P
+        node = P(tr.node_axes)
+        vmapped = jax.shard_map(vmapped, mesh=tr.mesh,
+                                in_specs=(node, node, node, node, P()),
+                                out_specs=(node, node, node),
+                                check_vma=False)
 
     def run_local_steps(state, batch, h_counts, lr):
-        params, opt, losses = jax.vmap(local_steps, in_axes=(0, 0, 0, 0, None))(
-            state.params, state.opt, batch, h_counts, lr)
+        params, opt, losses = vmapped(state.params, state.opt, batch,
+                                      h_counts, lr)
         return jax.tree.map(lambda x: shard(x, "param"), params), opt, losses
 
     def _metrics(losses, matched, mask, lr):
@@ -370,7 +383,7 @@ def make_swarm_step(cfg: SwarmConfig, loss_fn: Callable, opt_update: Callable,
         # 3. land: decode+average against the STALE packed model S
         sbuf = infl["sbuf"]
         if cfg.quantize:
-            m_rows = jnp.repeat(matched, layout.rows_per_node)
+            m_rows = B.row_mask(matched, layout.rows_per_node)
             base_buf = codec.decode_avg(recv, sbuf, m_rows)
         else:
             base_buf = (sbuf + recv[0]) * 0.5
@@ -469,8 +482,8 @@ def make_swarm_step(cfg: SwarmConfig, loss_fn: Callable, opt_update: Callable,
             layout = B.build_layout(params, block=tr.codec.block)
             enc = tr.codec.encode_state(B.pack(layout, params),
                                         jax.random.fold_in(rng, 0x5E))
-            m_rows = jnp.repeat(matched, layout.rows_per_node)
-            new_prev = tuple(jnp.where(m_rows[:, None], e, o)
+            m_rows = B.row_mask(matched, layout.rows_per_node)
+            new_prev = tuple(jnp.where(m_rows, e, o)
                              for e, o in zip(enc, state.prev))
         elif state.prev is not None:
             # comm copy refreshes on interaction. Blocking: to the

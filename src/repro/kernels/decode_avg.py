@@ -40,7 +40,9 @@ def _decode_avg_kernel(q_ref, s_ref, y_ref, o_ref, *, levels: int,
     s = s_ref[...]                                  # [TR, 1]
     y = y_ref[...].astype(jnp.float32)
     if pack4:
-        packed = q_ref[...]
+        # u8 -> f32 and sub-word shifts do not lower on the TPU: unpack
+        # the nibbles in int32
+        packed = q_ref[...].astype(jnp.int32)
         hcols = y.shape[1] // 2
         halves = []
         for lo_half, sl in ((True, slice(None, hcols)),
@@ -56,7 +58,7 @@ def _decode_avg_kernel(q_ref, s_ref, y_ref, o_ref, *, levels: int,
         o_ref[:, :hcols] = halves[0].astype(o_ref.dtype)
         o_ref[:, hcols:] = halves[1].astype(o_ref.dtype)
         return
-    q = q_ref[...].astype(jnp.float32)
+    q = q_ref[...].astype(jnp.int32).astype(jnp.float32)
     out = _decode(q, s, y, levels=levels, average=average)
     if m_ref is not None:
         out = jnp.where(m_ref[...] != 0, out, y)    # m: [TR, 1] f32 mask
@@ -65,7 +67,7 @@ def _decode_avg_kernel(q_ref, s_ref, y_ref, o_ref, *, levels: int,
 
 def decode_avg_pallas(q, s, y, *, bits: int = 8, average: bool = True,
                       matched=None, tile_rows: int = DEFAULT_TILE_ROWS,
-                      interpret: bool = True, pack4: bool = False):
+                      interpret: bool = False, pack4: bool = False):
     """q:[R,B] uint8/uint16 (or [R,B/2] packed), s:[R,1] f32, y:[R,B]
     -> (y + x̂)/2 (or x̂ if not average).
 
@@ -101,4 +103,5 @@ def decode_avg_pallas(q, s, y, *, bits: int = 8, average: bool = True,
         out_specs=pl.BlockSpec((tile_rows, block), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((n_rows, block), y.dtype),
         interpret=interpret,
+        name="decode_avg",
     )(*args)

@@ -33,22 +33,22 @@ def _encode_kernel(x_ref, ref_ref, u_ref, q_ref, s_ref, *, safety: float,
     dist = jnp.max(jnp.abs(x - r), axis=1, keepdims=True)      # [TR, 1]
     s = jnp.maximum(dist * (safety / half), min_scale)
     q = jnp.floor(x / s + u)                                   # stochastic round
-    q = jnp.mod(q, levels)
+    # codes are exact integers in [0, levels): the TPU has no direct
+    # f32 -> u8/u16 cast and no sub-word shifts, so the codes (and the
+    # nibble pack) live in int32 and narrow only at the store
+    q = jnp.mod(q, levels).astype(jnp.int32)
     if pack4:
         # fused bit-pack: two 4-bit codes per byte (half-split layout)
         hcols = q.shape[1] // 2
-        lo = q[:, :hcols].astype(jnp.uint8)
-        hi = q[:, hcols:].astype(jnp.uint8)
-        q_ref[...] = lo | (hi << 4)
-    else:
-        q_ref[...] = q.astype(q_ref.dtype)
+        q = q[:, :hcols] | (q[:, hcols:] << 4)
+    q_ref[...] = q.astype(q_ref.dtype)
     s_ref[...] = s
 
 
 def quantize_mod_pallas(x, ref, u, *, safety: float = 8.0,
                         min_scale: float = 1e-8, bits: int = 8,
                         tile_rows: int = DEFAULT_TILE_ROWS,
-                        interpret: bool = True, pack4: bool = False):
+                        interpret: bool = False, pack4: bool = False):
     """x, ref, u: [n_blocks, BLOCK] -> (q [n_blocks, BLOCK or BLOCK/2],
     s [n_blocks, 1]). q is uint8 (bits <= 8; BLOCK/2 wide when pack4) or
     uint16 (9..16 bits)."""
@@ -83,4 +83,5 @@ def quantize_mod_pallas(x, ref, u, *, safety: float = 8.0,
             jax.ShapeDtypeStruct((n_rows, 1), jnp.float32),
         ],
         interpret=interpret,
+        name="quantize_mod",
     )(x, ref, u)

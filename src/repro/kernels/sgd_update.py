@@ -7,7 +7,7 @@ fusing it into one kernel is the standard trick to avoid XLA materializing
 intermediates between the momentum update and the parameter write. This is
 the optimizer hot path: `optim/sgd.py` routes the momentum update through
 `kernels/ops.py::sgd_fused_update` on the packed flat buffer
-(core/bucket.py pack_flat), with the pure-jnp ref as the CPU fallback.
+(core/bucket.py pack_rows), with the pure-jnp ref as the CPU fallback.
 
 `lr` is a TRACED scalar — the engines drive it from `lr_fn(state.step)`
 inside jit — so it ships as a (1,) f32 SMEM operand rather than a static
@@ -62,4 +62,5 @@ def sgd_update_pallas(p, g, m, *, lr, mu: float = 0.9, wd: float = 0.0,
         out_shape=[jax.ShapeDtypeStruct((n_rows, cols), p.dtype),
                    jax.ShapeDtypeStruct((n_rows, cols), m.dtype)],
         interpret=interpret,
+        name="sgd_update",
     )(lr_arr, p, g, m)

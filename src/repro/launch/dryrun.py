@@ -1,6 +1,8 @@
 import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
-# ^ MUST precede any other import (jax locks device count on first init).
+os.environ["JAX_PLATFORMS"] = "cpu"
+# ^ MUST precede any other import (jax locks device count on first init);
+# the 512 placeholder devices are host devices, never the chip.
 """Multi-pod dry-run: lower + compile every (arch × input shape × mesh)
 against the production v5e mesh with 512 placeholder host devices, and emit
 the roofline terms (deliverables e and g).
@@ -28,8 +30,6 @@ from repro.models.unroll import set_unroll  # noqa: E402
 from repro.models.transformer import logits_head, param_template  # noqa: E402
 from repro.optim import make_optimizer  # noqa: E402
 from repro.roofline.analysis import analyze_compiled, model_flops  # noqa: E402
-
-from repro.compat import cost_analysis_dict as _cost_dict  # noqa: E402
 
 DEFAULT_H = 2
 
@@ -261,7 +261,7 @@ def run_one(arch: str, shape_name: str, mesh_kind: str, gossip: str = "gather",
     if flops_mode == "unrolled":
         t0 = time.time()
         lo_u = build(True)
-        ca = _cost_dict(lo_u.cost_analysis())
+        ca = lo_u.cost_analysis()
         flops_dev = float(ca.get("flops", 0.0)) / n_dev
         t_unroll = round(time.time() - t0, 1)
         del lo_u
@@ -284,7 +284,7 @@ def run_one(arch: str, shape_name: str, mesh_kind: str, gossip: str = "gather",
     collective_s = coll_bytes / ICI_LINK_BW
     terms = {"compute": compute_s, "memory": memory_s,
              "collective": collective_s}
-    rolled_ca = _cost_dict(compiled.cost_analysis())
+    rolled_ca = compiled.cost_analysis()
 
     return {
         "arch": arch, "shape": shape_name, "mesh": mesh_kind,

@@ -1,11 +1,34 @@
-"""Production mesh builders. FUNCTIONS only — importing this module never
-touches jax device state (device count is locked at first jax init, and the
-dry-run must set XLA_FLAGS before that)."""
+"""Mesh builders. FUNCTIONS only — importing this module never touches jax
+device state (device count is locked at first jax init, and the dry-run
+must set XLA_FLAGS before that)."""
 from __future__ import annotations
 
 import jax
 
-from repro.compat import make_mesh_compat  # noqa: F401  (re-export)
+
+def auto_mesh(shape, axes, devices=None):
+    """`jax.make_mesh` with GSPMD-propagated (Auto) axes. jax >= 0.7
+    defaults mesh axes to Explicit sharding-in-types, which the specs in
+    this repo (launch/specs.py, the node-stacked swarm state) do not use."""
+    return jax.make_mesh(shape, axes,
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes),
+                         devices=devices)
+
+
+def node_mesh(n_nodes: int, devices=None):
+    """The swarm's ("node",) mesh over `devices` (default: every device the
+    process sees), one node per device — or None on a single device, where
+    the nodes are vmapped on that one chip. More than one device with
+    n_nodes != device count raises: a node never spans or shares chips."""
+    devices = list(jax.devices() if devices is None else devices)
+    if len(devices) == 1:
+        return None
+    if n_nodes != len(devices):
+        raise ValueError(
+            f"{len(devices)} devices carry one swarm node each, but "
+            f"n_nodes={n_nodes}: run --nodes {len(devices)} (or restrict "
+            "the process to one device to vmap the nodes on it)")
+    return auto_mesh((len(devices),), ("node",), devices=devices)
 
 
 def make_production_mesh(*, multi_pod: bool = False):
@@ -17,17 +40,12 @@ def make_production_mesh(*, multi_pod: bool = False):
     """
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return make_mesh_compat(shape, axes)
+    return auto_mesh(shape, axes)
 
 
-def make_host_mesh(n_nodes: int = 1):
-    """CPU-scale mesh for the runnable examples/tests (1 device -> trivial)."""
-    n_dev = len(jax.devices())
-    n = min(n_nodes, n_dev)
-    return make_mesh_compat((n, n_dev // n), ("data", "model"))
-
-
-# TPU v5e hardware constants (per chip) — used by the roofline analysis.
+# TPU v5e hardware constants (per chip) — inputs of the roofline analysis
+# and the wall-clock cost model (sched/cost.py) only; no measured number is
+# ever reported as a share of them.
 PEAK_FLOPS_BF16 = 197e12     # FLOP/s
 HBM_BW = 819e9               # B/s
 ICI_LINK_BW = 50e9           # B/s per link (conservative single-link figure)

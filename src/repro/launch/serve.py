@@ -29,6 +29,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.configs import get_config, reduced
+from repro.launch.cache import use_compile_cache
 from repro.models import forward, init_cache, init_params
 from repro.models.multimodal import synth_prefix_embeds
 from repro.models.transformer import logits_head
@@ -260,6 +261,7 @@ def main():
     ap.add_argument("--wait-s", type=float, default=30.0)
     ap.add_argument("--live-steps", type=int, default=6)
     args = ap.parse_args()
+    use_compile_cache()
     if args.follow:
         args.source = "follow"
 

@@ -18,6 +18,7 @@ import time
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.algorithms import CAPABILITIES, make_algorithm, validate_run_config
 from repro.algorithms.sgp import sgp_init_state
@@ -28,6 +29,8 @@ from repro.core import (SwarmConfig, make_graph, sample_matching, swarm_init,
 from repro.core.exchange import static_ppermute_matching  # noqa: F401
 from repro.core.swarm import sample_h_counts
 from repro.data import DataConfig, SyntheticLMDataset, make_node_batches
+from repro.launch.cache import use_compile_cache
+from repro.launch.mesh import node_mesh
 from repro.models import init_params, loss_fn as model_loss
 from repro.optim import make_optimizer
 from repro.quant.schemes import ModularQuantConfig
@@ -41,13 +44,18 @@ def build_trainer(cfg, algo: str, n_nodes: int, H: int, lr: float,
                   overlap: bool = False, h_max: int = 8,
                   quant: ModularQuantConfig = None,
                   rate_profile: str = "none", codec: str = None,
-                  topology: str = None, compress_state: bool = False):
+                  topology: str = None, compress_state: bool = False,
+                  devices=None):
     """One construction path for EVERY algorithm (DESIGN.md §Baselines):
     validate the requested combination against the capability matrix,
     build ONE GossipTransport (whose wire codec comes from `codec`, the
     ``--codec`` spec — None follows the quant config = the q8 lattice),
     route all algorithms — swarm included — through make_algorithm with
-    the uniform factory signature."""
+    the uniform factory signature.
+
+    `devices` (default: every device the process sees) sets the node
+    mesh (launch/mesh.py node_mesh): one device vmaps every node on it;
+    several hold one node each, and the returned state is placed so."""
     caps = validate_run_config(algo, gossip_impl=gossip_impl,
                                quantize=quantize, nonblocking=nonblocking,
                                overlap=overlap, rate_profile=rate_profile,
@@ -81,7 +89,7 @@ def build_trainer(cfg, algo: str, n_nodes: int, H: int, lr: float,
     scfg = SwarmConfig(**skw)
     probe = jax.eval_shape(lambda k: init_params(k, cfg),
                            jax.random.PRNGKey(0))
-    transport = transport_from_config(scfg, graph, seed, probe)
+    transport = transport_from_config(scfg, graph, seed, probe, devices)
 
     kw = dict(loss_fn=lf, opt_update=opt.update, lr_fn=lr_fn,
               n_nodes=n_nodes, transport=transport)
@@ -102,7 +110,20 @@ def build_trainer(cfg, algo: str, n_nodes: int, H: int, lr: float,
     state = swarm_init(rng, scfg, lambda k: init_params(k, cfg), opt.init)
     if algo == "sgp":
         state = sgp_init_state(state, n_nodes, quantize)
+    state = place_nodes(state, node_mesh(n_nodes, devices))
     return jax.jit(step), state, scfg, graph
+
+
+def place_nodes(tree, mesh):
+    """Node-stacked leaves (leading [n_nodes] axis) one node per device of
+    the node mesh, scalars replicated; on one device (mesh None) the
+    leaves just become device arrays."""
+    if mesh is None:
+        return jax.tree.map(jnp.asarray, tree)
+    node = NamedSharding(mesh, P("node"))
+    repl = NamedSharding(mesh, P())
+    return jax.device_put(
+        tree, jax.tree.map(lambda x: node if np.ndim(x) else repl, tree))
 
 
 def parse_straggler(spec: "str | None"):
@@ -440,6 +461,7 @@ def main():
                          "points). 0 = one final checkpoint at --ckpt")
     ap.add_argument("--out", default=None, help="json metrics path")
     args = ap.parse_args()
+    use_compile_cache()
     # --eval-mean composes with the scan driver: the intermediate states
     # are consumed inside the fused scan, so μ is evaluated at CHUNK
     # BOUNDARIES (the checkpointable points) instead of per logged step —
@@ -489,6 +511,7 @@ def main():
     rng_np = np.random.default_rng(args.seed)
     key = jax.random.PRNGKey(args.seed + 1)
     h_max = scfg.h_loop_bound
+    mesh = node_mesh(args.nodes)
 
     schedule = trace = clocks = None
     n_steps = args.steps
@@ -653,9 +676,9 @@ def main():
                 print(json.dumps(rec))
                 continue
             nb = make_node_batches(ds, t, args.batch * h_max)
-            batch = {k: jnp.asarray(v.reshape(args.nodes, h_max, args.batch,
-                                              args.seq))
-                     for k, v in nb.items()}
+            batch = place_nodes({k: v.reshape(args.nodes, h_max, args.batch,
+                                              args.seq)
+                                 for k, v in nb.items()}, mesh)
             perm, h = perm_rows[t], h_rows[t]
             mask = mask_rows[t] if sched_on else None
             key, sub = jax.random.split(key)

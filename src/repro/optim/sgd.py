@@ -3,10 +3,10 @@
 This is the optimizer of the paper's experiments (momentum SGD with the
 sequential baseline's schedule, §5). The fused param/momentum update is a
 memory-bound hot-spot: the momentum path packs the whole model into ONE
-flat fp32 vector (core/bucket.py pack_flat — same wire layout as the
-gossip buffer) and runs a single `kernels.sgd_fused_update` sweep — the
-Pallas TPU kernel when REPRO_KERNEL_BACKEND selects it, the pure-jnp ref
-otherwise. The ref sweep replicates the historical per-leaf tree-map
+flat fp32 buffer (core/bucket.py pack_rows — same wire layout as the
+gossip buffer, as kernel rows) and runs a single `kernels.sgd_fused_update`
+sweep — the Pallas kernel on a TPU, the pure-jnp ref elsewhere
+(kernels/ops.py). The ref sweep replicates the historical per-leaf tree-map
 update op-for-op, so the fused path is bitwise identical to it (asserted
 in tests/test_kernels.py); `fused=False` keeps the per-leaf path as the
 oracle.
@@ -39,19 +39,20 @@ def sgd_init(cfg: SGDConfig, params):
 
 def _sgd_update_fused(cfg: SGDConfig, params, grads, state, lr):
     """One kernel sweep over the packed model: params/grads/momentum each
-    flatten to a [n_padded] fp32 vector (zero padding is a fixed point of
-    the update: m'=0, p'=0), update once, unpack with the original leaf
-    dtypes — exactly the per-leaf `upd` computation on a different layout."""
+    pack to [n_padded // block, block] fp32 rows (zero padding is a fixed
+    point of the update: m'=0, p'=0), update once, unpack with the original
+    leaf dtypes — exactly the per-leaf `upd` computation on a different layout."""
     from repro.core import bucket as B
     from repro.kernels import sgd_fused_update
     p_layout = B.build_flat_layout(params)
     m_layout = B.build_flat_layout(state["m"])
-    pbuf = B.pack_flat(p_layout, params)
-    gbuf = B.pack_flat(p_layout, grads)
-    mbuf = B.pack_flat(m_layout, state["m"])
+    pbuf = B.pack_rows(p_layout, params)
+    gbuf = B.pack_rows(p_layout, grads)
+    mbuf = B.pack_rows(m_layout, state["m"])
     pn, mn = sgd_fused_update(pbuf, gbuf, mbuf, lr=lr, mu=cfg.momentum,
-                              wd=cfg.weight_decay, nesterov=cfg.nesterov)
-    return B.unpack_flat(p_layout, pn), {"m": B.unpack_flat(m_layout, mn)}
+                              wd=cfg.weight_decay, nesterov=cfg.nesterov,
+                              block=p_layout.block)
+    return B.unpack_rows(p_layout, pn), {"m": B.unpack_rows(m_layout, mn)}
 
 
 def sgd_update(cfg: SGDConfig, params, grads, state, lr=None):

@@ -10,6 +10,7 @@ of overlap vs plain non-blocking, and the dispatch-before-local-steps /
 permute-only-collective claims (jaxpr inspection on a multi-device
 subprocess).
 """
+import os
 import subprocess
 import sys
 import textwrap
@@ -24,7 +25,7 @@ from repro.core import (SwarmConfig, make_graph, make_swarm_step,
                         sample_matching, swarm_init)
 from repro.core.simulator import run_superstep_oracle
 from repro.core.swarm import make_matching_pool
-from repro.launch.mesh import make_mesh_compat
+from repro.launch.mesh import auto_mesh
 from repro.optim import make_optimizer
 
 N, D, H, B, T = 8, 12, 2, 4, 10
@@ -103,7 +104,7 @@ def test_overlap_parity_all_transports(impl):
     pool = make_matching_pool(g, K=4, seed=0)
     idx_rng = np.random.default_rng(5)
     idxs = [int(idx_rng.integers(len(pool))) for _ in range(T)]
-    mesh = make_mesh_compat((1,), ("node",))
+    mesh = auto_mesh((1,), ("node",))
     if impl == "ppermute":
         # one static matching every superstep
         pairs = [(int(pool[1][d]), d) for d in range(N) if pool[1][d] != d]
@@ -228,10 +229,11 @@ _PIPELINE_JAXPR_SCRIPT = textwrap.dedent("""
     sys.path.insert(0, "src")
     import jax, jax.numpy as jnp, numpy as np
     from repro.core.swarm import SwarmConfig, make_swarm_step, swarm_init
+    from repro.launch.mesh import auto_mesh
     from repro.optim import make_optimizer
 
     N = 8
-    mesh = jax.make_mesh((N,), ("node",))
+    mesh = auto_mesh((N,), ("node",))
     pairs = [(0, 1), (1, 0), (2, 3), (3, 2)]
     scfg = SwarmConfig(n_nodes=N, H=2, nonblocking=True, overlap=True,
                        quantize=True, gossip_impl="ppermute",
@@ -272,7 +274,8 @@ def test_pipelined_superstep_dispatches_before_local_loop():
     dependence on the local compute and latency-hiding scheduling can
     overlap the wire exchange with it."""
     out = subprocess.run([sys.executable, "-c", _PIPELINE_JAXPR_SCRIPT],
-                         capture_output=True, text=True, timeout=300)
+                         capture_output=True, text=True, timeout=300,
+                         env={**os.environ, "JAX_PLATFORMS": "cpu"})
     assert out.returncode == 0, out.stderr[-2000:]
     got = dict(line.split() for line in out.stdout.strip().splitlines())
     assert got["n_ppermute"] == "2"

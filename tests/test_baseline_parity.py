@@ -68,8 +68,8 @@ def _build(algo, impl, *, quantize=False, nonblocking=False, seed=0,
     opt = make_optimizer("sgd", lr=LR, momentum=0.0)
     tr_kw = {}
     if pool is not None:
-        from repro.compat import make_mesh_compat
-        tr_kw = dict(mesh=make_mesh_compat((1,), ("node",)), node_axes=(),
+        from repro.launch.mesh import auto_mesh
+        tr_kw = dict(mesh=auto_mesh((1,), ("node",)), node_axes=(),
                      matching_pool=pool)
     if codec is not None:
         tr_kw["codec"] = make_codec(codec, quant)

@@ -2,6 +2,7 @@
 flat ≡ legacy per-leaf gossip (bit-for-bit exact / tolerance quantized),
 payload-byte accounting, and the one-collective-per-payload-tensor claim
 (jaxpr inspection on a multi-device subprocess)."""
+import os
 import subprocess
 import sys
 import textwrap
@@ -189,7 +190,8 @@ def test_single_ppermute_per_payload_tensor():
     per-leaf legacy path issues one per leaf. Counted in the jaxpr on an
     8-fake-device subprocess (device count is locked at jax import)."""
     out = subprocess.run([sys.executable, "-c", _PPERMUTE_COUNT_SCRIPT],
-                         capture_output=True, text=True, timeout=300)
+                         capture_output=True, text=True, timeout=300,
+                         env={**os.environ, "JAX_PLATFORMS": "cpu"})
     assert out.returncode == 0, out.stderr[-2000:]
     counts = dict(line.split() for line in out.stdout.strip().splitlines())
     assert counts["flat_exact"] == "1"
@@ -203,7 +205,7 @@ def test_pool_average_momentum_uses_actual_partners():
     (regression: it used to index momenta by the pool index itself)."""
     from jax.sharding import PartitionSpec as P
     from repro.core.swarm import make_matching_pool
-    from repro.launch.mesh import make_mesh_compat
+    from repro.launch.mesh import auto_mesh
 
     def tiny_init(rng):
         return {"w": jax.random.normal(rng, (4, 3)) * 0.3}
@@ -220,7 +222,7 @@ def test_pool_average_momentum_uses_actual_partners():
     g = make_graph("complete", N)
     pool = make_matching_pool(g, K=3, seed=0)
     opt = make_optimizer("sgd", lr=0.1, momentum=0.9)
-    mesh = make_mesh_compat((1,), ("node",))
+    mesh = auto_mesh((1,), ("node",))
     idx = 1
 
     def run(impl):

@@ -18,8 +18,8 @@ N = 4
 def _mesh():
     # single CPU device: trivial 1x1 mesh — shard_map still exercises the
     # ppermute code path (self-permutes)
-    from repro.launch.mesh import make_mesh_compat
-    return make_mesh_compat((1, 1), ("data", "model"))
+    from repro.launch.mesh import auto_mesh
+    return auto_mesh((1, 1), ("data", "model"))
 
 
 def test_matching_pool_valid():

@@ -12,6 +12,7 @@ its own XLA_FLAGS fake-device count):
   scales), and exactly pool_entries x per-branch collectives for the
   two-tier lax.switch pool.
 """
+import os
 import subprocess
 import sys
 import textwrap
@@ -134,8 +135,10 @@ _HIER_COLLECTIVE_SCRIPT = textwrap.dedent("""
 
 
 def _run(script):
+    # a host-device rehearsal: never reach for an accelerator
     out = subprocess.run([sys.executable, "-c", script],
-                         capture_output=True, text=True, timeout=600)
+                         capture_output=True, text=True, timeout=600,
+                         env={**os.environ, "JAX_PLATFORMS": "cpu"})
     assert out.returncode == 0, out.stderr[-3000:]
     pairs = []
     for line in out.stdout.strip().splitlines():

@@ -15,18 +15,26 @@ import numpy as np
 import pytest
 
 from repro.checkpoint import load_checkpoint, save_checkpoint
-from repro.compat import donation_alias_count, memory_analysis_compat
 from repro.core import (SwarmConfig, make_graph, make_superstep_scan,
                         make_swarm_step, sample_matching, swarm_init)
 from repro.core.swarm import (codec_checkpoint_tree, make_matching_pool,
                               restore_codec_state)
-from repro.launch.mesh import make_mesh_compat
+from repro.launch.mesh import auto_mesh
 from repro.optim import make_optimizer
 from repro.quant.schemes import ModularQuantConfig
 
 N, D, H, B, T = 8, 12, 2, 4, 6
 LR = 0.05
 QCFG = ModularQuantConfig(safety=16.0)
+
+
+def donation_alias_count(lowered) -> int:
+    """How many input buffers a lowered computation actually aliases to
+    outputs (donation applied, not just requested). StableHLO marks inputs
+    aliased to a fixed output `tf.aliasing_output` and donors whose
+    aliasing is decided at compile time `jax.buffer_donor` — count both."""
+    txt = lowered.as_text()
+    return txt.count("tf.aliasing_output") + txt.count("jax.buffer_donor")
 
 
 def _data(S, seed=42, h_slots=H):
@@ -139,13 +147,13 @@ def test_scan_bitwise_matches_per_step(name, skw, impl):
         pool = make_matching_pool(g, K=4, seed=0)
         static = np.asarray(pool[1], np.int32)
         pairs = [(int(static[d]), d) for d in range(N) if static[d] != d]
-        kw = dict(mesh=make_mesh_compat((1,), ("node",)), node_axes=(),
+        kw = dict(mesh=auto_mesh((1,), ("node",)), node_axes=(),
                   static_pairs=pairs)
         perms = np.stack([static] * T)
         hs = np.full((T, N), H, np.int32)
     elif impl == "ppermute_pool":
         pool = make_matching_pool(g, K=4, seed=0)
-        kw = dict(mesh=make_mesh_compat((1,), ("node",)), node_axes=(),
+        kw = dict(mesh=auto_mesh((1,), ("node",)), node_axes=(),
                   matching_pool=pool)
         r = np.random.default_rng(5)
         perms = np.stack([np.full((N,), int(r.integers(len(pool))), np.int32)
@@ -233,9 +241,8 @@ def test_stacked_engine_inputs_pool_broadcast():
 def test_chunk_donation_actually_aliases():
     """Donation regression (satellite): the chunk jit must alias the
     donated SwarmState/key input buffers to outputs — asserted on the
-    lowered module's aliasing markers (compat shim spans jax versions),
-    with the compiled memory stats cross-checked where the backend
-    reports them. And the donated inputs must actually die."""
+    lowered module's aliasing markers, with the compiled memory stats
+    cross-checked. And the donated inputs must actually die."""
     X, Y = _data(4)
     perms, hs = _gather_inputs(4)
     scfg = SwarmConfig(n_nodes=N, H=H, quantize=True, quant=QCFG,
@@ -249,9 +256,7 @@ def test_chunk_donation_actually_aliases():
     n_donated = len(jax.tree.leaves(state)) + 1   # + the rng key
     assert donation_alias_count(lowered) >= n_donated, \
         "donated superstep inputs are not aliased in the lowered module"
-    stats = memory_analysis_compat(lowered.compile())
-    if stats is not None and hasattr(stats, "alias_size_in_bytes"):
-        assert stats.alias_size_in_bytes > 0
+    assert lowered.compile().memory_analysis().alias_size_in_bytes > 0
 
     new_state, new_key, _ = chunk_fn(*args)
     for x in jax.tree.leaves(state):

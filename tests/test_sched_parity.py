@@ -27,7 +27,7 @@ import pytest
 from repro.core import SwarmConfig, make_graph, make_swarm_step, swarm_init
 from repro.core.simulator import run_events_oracle, run_superstep_oracle
 from repro.core.swarm import make_matching_pool
-from repro.launch.mesh import make_mesh_compat
+from repro.launch.mesh import auto_mesh
 from repro.optim import make_optimizer
 from repro.sched import (RateProfile, StragglerConfig, bin_trace,
                          engine_inputs, generate_trace, pool_edges,
@@ -133,10 +133,10 @@ def test_bridged_engine_matches_oracle(impl, mode, nonblocking, overlap):
                        gossip_impl=impl, track_potential=False)
     kw = {}
     if impl == "ppermute":
-        kw = dict(mesh=make_mesh_compat((1,), ("node",)), node_axes=(),
+        kw = dict(mesh=auto_mesh((1,), ("node",)), node_axes=(),
                   static_pairs=static[0])
     elif impl == "ppermute_pool":
-        kw = dict(mesh=make_mesh_compat((1,), ("node",)), node_axes=(),
+        kw = dict(mesh=auto_mesh((1,), ("node",)), node_axes=(),
                   matching_pool=pool)
     step, state = _make_engine(scfg, **kw)
     x0 = np.asarray(state.params["w"], np.float32)
