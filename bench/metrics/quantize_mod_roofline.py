@@ -1,0 +1,7 @@
+"""quantize_mod_roofline: share of its HBM roofline that the `quantize_mod` Pallas
+kernel reached in the window (bench/readers.py kernel_roofline)."""
+from bench.readers import kernel_roofline
+
+
+def read(r):
+    return kernel_roofline(r, "quantize_mod")
