@@ -55,6 +55,7 @@ import numpy as np
 
 from repro.models import forward, init_cache
 from repro.models.transformer import logits_head
+from repro.profiling import install_gc_spans, span
 from repro.serve import paged as P
 from repro.serve.metrics import ServeMetrics
 from repro.serve.swap import HotSwap
@@ -215,6 +216,7 @@ class ServeEngine:
             cfg, ecfg.max_slots, ecfg.kv_capacity, dtype)
         if params is not None:
             self.swap.publish(params, t_landed=time.time(), tag="init")
+        install_gc_spans()
 
     # -- compiled serving fns (each compiles exactly once) -----------------
 
@@ -444,7 +446,8 @@ class ServeEngine:
                 ln.prefilling, ln.pos, ln.tokens = True, 0, []
                 self._caches = self._reset(self._caches, i)
             else:
-                self._admit_blocking(i, ln, params)
+                with span("serve.prefill"):
+                    self._admit_blocking(i, ln, params)
 
     def _admit_blocking(self, i: int, ln: _Lane, params):
         """Legacy blocking admission: batch-1 prefill at the prompt's own
@@ -496,51 +499,100 @@ class ServeEngine:
                         for ln in self.lanes])
         if not pre.any():
             return 0
-        chunks = np.zeros((slots, T), np.int32)
-        nv = np.zeros((slots,), np.int32)
-        fin = np.zeros((slots,), bool)
-        for i, ln in enumerate(self.lanes):
-            if pre[i]:
-                L = ln.prompt.shape[0]
-                n = min(T, L - ln.pos)
-                chunks[i, :n] = ln.prompt[ln.pos:ln.pos + n]
-                nv[i], fin[i] = n, ln.pos + n >= L
-        self._key, sub = jax.random.split(self._key)
-        toks, self._caches, self._pools = self._chunk_fn(
-            params, self._caches, self._pools, self._tokens,
-            jnp.asarray(chunks), jnp.asarray(nv), jnp.asarray(pre),
-            jnp.asarray(fin), sub)
-        self._tokens = toks
-        committed = 0
-        toks_np = np.asarray(toks) if fin.any() else None   # sync point
-        t_now = time.time()
-        for i, ln in enumerate(self.lanes):
-            if not pre[i]:
-                continue
-            ln.pos += int(nv[i])
-            if fin[i]:
-                ln.prefilling = False
-                ln.tokens = [int(toks_np[i, 0])]
-                ln.remaining = ln.budget - 1
-                ln.t_first = ln.t_last = t_now
-                self.metrics.record_ttft(t_now - ln.t_submit)
-                self.metrics.tokens_committed += 1
-                self.metrics.record_first_token(ln.gen, t_now)
-                committed += 1
-                if ln.remaining <= 0:
-                    self._retire(i)
+        with span("serve.chunk"):
+            with span("serve.chunk.prep"):
+                chunks = np.zeros((slots, T), np.int32)
+                nv = np.zeros((slots,), np.int32)
+                fin = np.zeros((slots,), bool)
+                for i, ln in enumerate(self.lanes):
+                    if pre[i]:
+                        L = ln.prompt.shape[0]
+                        n = min(T, L - ln.pos)
+                        chunks[i, :n] = ln.prompt[ln.pos:ln.pos + n]
+                        nv[i], fin[i] = n, ln.pos + n >= L
+                valid = int(nv.sum())
+                self.metrics.record_chunk(slots * T, valid)
+            with span("serve.chunk.dispatch", lanes=slots,
+                      lanes_valid=int(pre.sum()), tokens_valid=valid,
+                      tokens_computed=slots * T, finished=int(fin.sum())):
+                self._key, sub = jax.random.split(self._key)
+                toks, self._caches, self._pools = self._chunk_fn(
+                    params, self._caches, self._pools, self._tokens,
+                    jnp.asarray(chunks), jnp.asarray(nv), jnp.asarray(pre),
+                    jnp.asarray(fin), sub)
+                self._tokens = toks
+            with span("serve.chunk.sync"):
+                toks_np = np.asarray(toks) if fin.any() else None
+            with span("serve.chunk.harvest"):
+                committed = 0
+                t_now = time.time()
+                for i, ln in enumerate(self.lanes):
+                    if not pre[i]:
+                        continue
+                    ln.pos += int(nv[i])
+                    if fin[i]:
+                        ln.prefilling = False
+                        ln.tokens = [int(toks_np[i, 0])]
+                        ln.remaining = ln.budget - 1
+                        ln.t_first = ln.t_last = t_now
+                        self.metrics.record_ttft(t_now - ln.t_submit)
+                        self.metrics.tokens_committed += 1
+                        self.metrics.record_first_token(ln.gen, t_now)
+                        committed += 1
+                        if ln.remaining <= 0:
+                            self._retire(i)
         return committed
+
+    def _step_decode(self, g: int, params) -> int:
+        """One decode dispatch over every lane; generation g's decoding
+        lanes commit. Returns tokens committed."""
+        commit = np.array([ln.active and ln.gen == g and
+                           not ln.prefilling and ln.remaining > 0
+                           for ln in self.lanes])
+        if not commit.any():
+            return 0
+        with span("serve.decode"):
+            with span("serve.decode.prep"):
+                n = int(commit.sum())
+                self.metrics.record_decode(self.ecfg.max_slots, n)
+                self._key, sub = jax.random.split(self._key)
+            with span("serve.decode.dispatch", lanes=self.ecfg.max_slots,
+                      committed=n):
+                toks, self._caches, self._pools = self._decode(
+                    params, self._caches, self._pools, self._tokens,
+                    jnp.asarray(commit), sub)
+                self._tokens = toks
+            with span("serve.decode.sync"):
+                toks_np = np.asarray(toks)
+            with span("serve.decode.harvest"):
+                t_now = time.time()
+                for i, ln in enumerate(self.lanes):
+                    if commit[i]:
+                        ln.tokens.append(int(toks_np[i, 0]))
+                        ln.remaining -= 1
+                        self.metrics.record_token_gap(t_now - ln.t_last)
+                        ln.t_last = t_now
+                self.metrics.tokens_committed += n
+        return n
 
     def step(self) -> int:
         """One engine iteration: poll -> adopt -> admit -> per live
         generation one chunk dispatch (chunked prefill) + one decode
-        dispatch -> harvest. Returns # tokens committed."""
+        dispatch -> harvest -> retire. Returns # tokens committed.
+
+        Each phase is a `serve.*` host span (repro.profiling); the
+        dispatch spans carry the lanes computed and committed."""
         now = time.time()
         if self.metrics.t_start is None:
             self.metrics.t_start = now
-        self.poll_source()
-        self._try_adopt()
-        self._admit(now)
+        with span("serve.admit") as sp:
+            queued, deferred = len(self.queue), self.metrics.pool_deferrals
+            self.poll_source()
+            self._try_adopt()
+            self._admit(now)
+            sp.set_metadata(admitted=queued - len(self.queue),
+                            deferred=self.metrics.pool_deferrals - deferred,
+                            queue=len(self.queue))
         committed = 0
         # one masked dispatch per live generation (usually one; two while
         # a swap drains) — identical shapes, so each is a jit-cache hit
@@ -548,40 +600,18 @@ class ServeEngine:
             params = self.live[g]
             if self.ecfg.prefill_chunk > 0:
                 committed += self._step_chunks(g, params)
-            commit = np.array([ln.active and ln.gen == g and
-                               not ln.prefilling and ln.remaining > 0
-                               for ln in self.lanes])
-            if not commit.any():
-                continue
-            self._key, sub = jax.random.split(self._key)
-            t0 = time.time()
-            toks, self._caches, self._pools = self._decode(
-                params, self._caches, self._pools, self._tokens,
-                jnp.asarray(commit), sub)
-            toks_np = np.asarray(toks)     # sync point
-            t_now = time.time()
-            self._tokens = toks
-            n = 0
+            committed += self._step_decode(g, params)
+        with span("serve.retire"):
             for i, ln in enumerate(self.lanes):
-                if commit[i]:
-                    ln.tokens.append(int(toks_np[i, 0]))
-                    ln.remaining -= 1
-                    self.metrics.record_token_gap(t_now - ln.t_last)
-                    ln.t_last = t_now
-                    n += 1
-            committed += n
-            self.metrics.tokens_committed += n
-            self.metrics.record_step(t_now - t0, n)
-        for i, ln in enumerate(self.lanes):
-            if ln.active and not ln.prefilling and ln.remaining <= 0:
-                self._retire(i)
-        self._gc_live()
-        self.metrics.t_end = time.time()
-        self.metrics.decode_cache_misses = max(
-            0, self._decode._cache_size() - 1)
-        if self.ecfg.prefill_chunk > 0:
-            self.metrics.prefill_cache_misses = max(
-                0, self._chunk_fn._cache_size() - 1)
+                if ln.active and not ln.prefilling and ln.remaining <= 0:
+                    self._retire(i)
+            self._gc_live()
+            self.metrics.t_end = time.time()
+            self.metrics.decode_cache_misses = max(
+                0, self._decode._cache_size() - 1)
+            if self.ecfg.prefill_chunk > 0:
+                self.metrics.prefill_cache_misses = max(
+                    0, self._chunk_fn._cache_size() - 1)
         return committed
 
     def drain(self, max_steps: int = 10_000):

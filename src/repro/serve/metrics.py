@@ -17,6 +17,10 @@ Collected host-side by the engine, zero device traffic:
   counter (bounded queue = the backpressure signal);
 * paged-KV pool    — pages in use (peak), admissions deferred on pool
   exhaustion, and pool vs dense-bank device bytes (serve/paged.py);
+* lane use         — decode lanes committed over lanes computed, and
+  chunk tokens valid over tokens computed: every dispatch computes every
+  slot, so these say how much of it is work (the same numbers as the
+  `serve.*.dispatch` spans' stats, engine.py);
 * freshness        — time-to-fresh-model: checkpoint-lands (the source's
   ``t_landed``) -> first token COMMITTED from a sequence admitted under
   that generation. The serving-side half of the paper's asynchrony story:
@@ -37,12 +41,15 @@ def percentile(xs: List[float], p: float) -> float:
     return s[k]
 
 
+def _share(part: int, whole: int) -> Optional[float]:
+    return round(part / whole, 4) if whole else None
+
+
 @dataclass
 class ServeMetrics:
     token_latencies_s: List[float] = field(default_factory=list)
     ttft_s: List[float] = field(default_factory=list)
     queue_wait_s: List[float] = field(default_factory=list)
-    step_times_s: List[float] = field(default_factory=list)
     queue_depths: List[int] = field(default_factory=list)
     tokens_committed: int = 0
     rejected: int = 0
@@ -52,6 +59,11 @@ class ServeMetrics:
     decode_cache_misses: int = 0        # must stay 0 after warmup
     prefill_cache_misses: int = 0       # chunked prefill: must stay 0 too
     swaps_adopted: int = 0
+    # every dispatch computes every lane; these count how much commits
+    decode_lanes_computed: int = 0
+    decode_lanes_committed: int = 0
+    chunk_tokens_computed: int = 0      # slots x chunk length
+    chunk_tokens_valid: int = 0         # prompt tokens in the chunks
     # paged KV pool (all 0 when the engine runs dense)
     pool_deferrals: int = 0             # admissions deferred: no pages
     pool_pages_peak: int = 0
@@ -66,11 +78,13 @@ class ServeMetrics:
 
     # -- recording ---------------------------------------------------------
 
-    def record_step(self, dt_s: float, n_tokens: int):
-        """Wall time of one decode dispatch (diagnostic series only —
-        per-token latency is commit-gap based, see module docstring)."""
-        if n_tokens > 0:
-            self.step_times_s.append(dt_s)
+    def record_decode(self, lanes: int, committed: int):
+        self.decode_lanes_computed += lanes
+        self.decode_lanes_committed += committed
+
+    def record_chunk(self, computed: int, valid: int):
+        self.chunk_tokens_computed += computed
+        self.chunk_tokens_valid += valid
 
     def record_token_gap(self, dt_s: float):
         self.token_latencies_s.append(dt_s)
@@ -136,6 +150,10 @@ class ServeMetrics:
             "kv_bytes": self.kv_bytes,
             "kv_dense_bytes": self.kv_dense_bytes,
             "swaps_adopted": self.swaps_adopted,
+            "decode_lane_use": _share(self.decode_lanes_committed,
+                                      self.decode_lanes_computed),
+            "chunk_fill": _share(self.chunk_tokens_valid,
+                                 self.chunk_tokens_computed),
             "time_to_fresh_s": [round(x, 4) for x in fresh],
             "time_to_fresh_max_s": round(max(fresh), 4) if fresh else None,
         }
