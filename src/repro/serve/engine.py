@@ -328,10 +328,13 @@ class ServeEngine:
 
         self._prefill = jax.jit(prefill)
         self._install = jax.jit(install)
-        self._decode = jax.jit(decode_masked)
-        self._chunk_fn = jax.jit(chunk_masked)
+        # the lane cache bank and the page pools are donated: each call
+        # replaces them (the engine rebinds both from its outputs), so the
+        # new KV rows are written into the pools where they lie
+        self._decode = jax.jit(decode_masked, donate_argnums=(1, 2))
+        self._chunk_fn = jax.jit(chunk_masked, donate_argnums=(1, 2))
         self._reset = jax.jit(reset_lane)
-        self._install_pool = jax.jit(install_pool)
+        self._install_pool = jax.jit(install_pool, donate_argnums=0)
 
     def _init_cache_bank(self):
         one = init_cache(self.cfg, 1, self.ecfg.kv_capacity)

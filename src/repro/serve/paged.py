@@ -17,11 +17,12 @@ page). Only ``mixer == "attn"`` layers page.
 Bitwise contract: decode reconstructs a lane's contiguous cache with
 ``attention.gather_pages`` — same rows, same order, same shape as the
 dense bank — so the paged engine's token stream is bit-for-bit the dense
-engine's (tests/test_serve.py). The write side is a masked one-hot
-scatter (:func:`scatter_rows`): every hit pool row receives exactly one
-``1.0 * new`` term plus zeros, which is exact, and page tables are
-disjoint across lanes by the allocator's invariant, so no row is ever
-hit twice.
+engine's (tests/test_serve.py). The write side (:func:`scatter_rows`)
+stores each committed row into its pool row with one indexed scatter per
+pool, masked rows dropped; the engine donates the pools, so the scatter
+updates them in place and no other pool row is read or copied. Exact:
+page tables are disjoint across lanes by the allocator's invariant, so
+no row is ever written twice.
 """
 from __future__ import annotations
 
@@ -139,39 +140,37 @@ def split_new_rows(new_caches):
 
 
 def scatter_rows(pool, rows, pages, lens, n_valid, commit, page: int):
-    """Masked one-hot scatter of per-lane KV rows into a page pool.
+    """Write per-lane KV rows into a page pool, in place.
 
     pool:[(L,) G, page, kv, hd]; rows:[slots, (L,) T, kv, hd] (an extra
     B=1 axis before T — vmap residue — is squeezed); pages:[slots, n_pp]
     page tables; lens/n_valid:[slots] int32; commit:[slots] bool. Lane
     b's token t lands at position ``lens[b] + t`` = row ``pos % page`` of
     page ``pages[b, pos // page]``, iff ``commit[b] and t < n_valid[b]``.
-    Exact: page tables are disjoint across lanes and positions distinct
-    within one, so each pool row gets at most one ``1.0 * x`` term."""
+    One indexed scatter per pool: a masked token's page index is ``G``,
+    out of range, so ``mode="drop"`` discards it, and every other pool row
+    keeps its bytes. Exact: page tables are disjoint across lanes and
+    positions distinct within one, so no row is written twice. With the
+    pool donated (serve/engine.py) the scatter updates it where it lies."""
     if rows.ndim == pool.ndim + 1:
         rows = rows.squeeze(-4)
-    G, P = (pool.shape[1], pool.shape[2]) if pool.ndim == 5 \
-        else (pool.shape[0], pool.shape[1])
+    G, P = pool.shape[-4], pool.shape[-3]
     assert P == page, (P, page)
     T = rows.shape[-3]
     t = jnp.arange(T)
     pos = lens[:, None] + t[None, :]                        # [slots,T]
     # out-of-table positions only occur at length-masked tokens (ok below
     # is False there); take_along_axis clips, so the read is always safe
-    pid = jnp.take_along_axis(pages, pos // page, axis=1)   # [slots,T]
-    ok = commit[:, None] & (t[None, :] < n_valid[:, None])
-    M = ok[:, :, None, None] & \
-        (pid[:, :, None, None] == jnp.arange(G)[None, None, :, None]) & \
-        ((pos % page)[:, :, None, None] ==
-         jnp.arange(P)[None, None, None, :])                # [slots,T,G,P]
-    Mf = M.astype(pool.dtype)
-    if pool.ndim == 5:
-        scat = jnp.einsum("btgr,bltkh->lgrkh", Mf, rows.astype(pool.dtype))
-        hit = M.any(axis=(0, 1))[None, :, :, None, None]
-    else:
-        scat = jnp.einsum("btgr,btkh->grkh", Mf, rows.astype(pool.dtype))
-        hit = M.any(axis=(0, 1))[:, :, None, None]
-    return jnp.where(hit, scat, pool)
+    pid = jnp.take_along_axis(pages, pos // page, axis=1, mode="clip")
+    # an unallocated (-1) table entry is dropped too: a negative index
+    # would wrap onto the pool's last page, which another lane may own
+    ok = commit[:, None] & (t[None, :] < n_valid[:, None]) & (pid >= 0)
+    pid = jnp.where(ok, pid, G)                             # G: dropped
+    rows = rows.astype(pool.dtype)
+    if pool.ndim == 5:              # scanned blocks: [L, G, page, kv, hd]
+        return pool.at[:, pid, pos % page].set(
+            jnp.moveaxis(rows, 1, 0), mode="drop")
+    return pool.at[pid, pos % page].set(rows, mode="drop")
 
 
 def scatter_tree(pools, rows, pages, lens, n_valid, commit, page: int):

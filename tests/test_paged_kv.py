@@ -6,7 +6,8 @@ Three layers of coverage:
   exhaustion (rejection, never corruption), no page aliasing across live
   grants, full free-list restoration;
 * scatter/gather units: a pool scatter followed by ``gather_pages`` is the
-  identity onto the contiguous cache layout;
+  identity onto the contiguous cache layout, and the in-place row write
+  matches a row-by-row NumPy oracle;
 * engine integration: ragged-prompt admission on an SSM and an attention
   arch, pool-exhaustion deferral (second backpressure signal), oversize
   rejection, and the paged/chunked engines' bitwise agreement with the
@@ -142,6 +143,56 @@ def test_scatter_then_gather_is_contiguous_identity():
     pool = P.scatter_rows(pool, rows, tables, lens, n_valid,
                           jnp.asarray([False, False]), page)
     np.testing.assert_array_equal(np.asarray(pool), np.asarray(before))
+
+
+def _scatter_oracle(pool, rows, tables, lens, n_valid, commit, page):
+    """Row-by-row NumPy write: lane b's token t goes to row (lens+t) % page
+    of page tables[b, (lens+t) // page], iff commit[b] and t < n_valid[b]."""
+    out = np.array(pool)
+    for b in range(rows.shape[0]):
+        for t in range(rows.shape[-3]):
+            if not commit[b] or t >= n_valid[b]:
+                continue
+            pos = lens[b] + t
+            pid, r = tables[b, pos // page], pos % page
+            if out.ndim == 5:
+                out[:, pid, r] = rows[b, :, t]
+            else:
+                out[pid, r] = rows[b, t]
+    return out
+
+
+@pytest.mark.parametrize("commit", [(True, True, True), (True, False, True)],
+                         ids=["all-commit", "lane1-masked"])
+@pytest.mark.parametrize("T", [1, 6], ids=["decode", "chunk"])
+@pytest.mark.parametrize("layers", [0, 3], ids=["tail-4d", "blocks-5d"])
+def test_scatter_rows_matches_numpy_oracle(layers, T, commit):
+    """The in-place row write against a row-by-row NumPy oracle: lanes
+    that start mid-page and cross page boundaries, a lane masked by
+    `commit`, tokens past `n_valid` (some past the lane's page table).
+    Every hit row holds its new row and every other row keeps its bytes."""
+    page, n_pp, G, kvh, hd = 4, 3, 10, 2, 5
+    lead = (layers,) if layers else ()
+    rng = np.random.default_rng(T + layers)
+    pool = rng.normal(size=lead[:1] + (G, page, kvh, hd)).astype(np.float32)
+    tables = np.asarray([[5, 1, 7], [2, 6, 0], [9, 3, 8]], np.int32)
+    if T == 1:
+        lens, n_valid = [2, 3, 11], [1, 0, 1]          # lane 1: no token
+    else:
+        lens, n_valid = [2, 3, 7], [6, 4, 5]           # lane 2 ends at row 11
+    lens, n_valid = np.asarray(lens, np.int32), np.asarray(n_valid, np.int32)
+    commit = np.asarray(commit)
+    rows = rng.normal(size=(3,) + lead + (T, kvh, hd)).astype(np.float32)
+    got = P.scatter_rows(jnp.asarray(pool), jnp.asarray(rows),
+                         jnp.asarray(tables), jnp.asarray(lens),
+                         jnp.asarray(n_valid), jnp.asarray(commit), page)
+    want = _scatter_oracle(pool, rows, tables, lens, n_valid, commit, page)
+    np.testing.assert_array_equal(np.asarray(got), want)
+    n_hit = int(sum(min(T, n) for n, c in zip(n_valid, commit) if c))
+    changed = (want != pool).any(axis=(-2, -1))
+    if layers:
+        changed = changed.any(axis=0)
+    assert n_hit > 0 and int(changed.sum()) == n_hit
 
 
 # ---------------------------------------------------------------------------

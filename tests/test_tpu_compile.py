@@ -173,3 +173,56 @@ def test_local_steps_compile_compact(one_chip, wmt_nodes, monkeypatch):
                  _spec((N_NODES,), jnp.int32, one_chip))
     assert 'custom_call_target="tpu_custom_call"' in c.as_text()
     assert c.memory_analysis().generated_code_size_in_bytes < CODE_BYTES_MAX
+
+
+# The paged serving engine's two per-step programs at a small paged
+# configuration with the real head width (128) and a page of 16 rows:
+# the new KV rows are written into the donated page pools in place, so
+# no pool-sized buffer is copied, selected or contracted.
+@pytest.mark.parametrize("n_layers,pattern", [(2, 1), (5, 2)],
+                         ids=["blocks", "blocks+tail"])
+def test_paged_engine_writes_pools_in_place(one_chip, n_layers, pattern):
+    import dataclasses
+    import math
+    import re
+    from repro.configs.base import get_config, reduced
+    from repro.models import init_params
+    from repro.serve import EngineConfig, ServeEngine
+    from repro.serve import paged as P
+    cfg = reduced(get_config("olmo-1b"), n_layers=n_layers, d_model=256)
+    cfg = dataclasses.replace(cfg, pattern=cfg.pattern * pattern,
+                              dtype="bfloat16", n_heads=8, n_kv_heads=8,
+                              head_dim=128)
+    # 19 pages: no other tensor of the programs has a pool's element count
+    ecfg = EngineConfig(max_slots=4, prompt_len=48, max_new_tokens=16,
+                        paged=True, page_size=16, n_pages=19,
+                        prefill_chunk=16)
+    eng = ServeEngine(cfg, ecfg)
+    pools = jax.tree.leaves(eng._pools)
+    assert {p.ndim for p in pools} == ({5} if pattern == 1 else {4, 5})
+    pool_bytes = P.tree_num_bytes(eng._pools)
+    shapes = {"[" + ",".join(map(str, p.shape)) + "]" for p in pools}
+    sizes = {p.size for p in pools}
+
+    def specs(tree):
+        return jax.tree.map(lambda x: _spec(x.shape, x.dtype, one_chip), tree)
+    S, T = ecfg.max_slots, ecfg.prefill_chunk
+    params = specs(jax.eval_shape(
+        lambda: init_params(jax.random.PRNGKey(0), cfg)))
+    key = specs(jax.eval_shape(lambda: jax.random.PRNGKey(0)))
+    i32 = lambda *s: _spec(s, jnp.int32, one_chip)
+    flag = _spec((S,), jnp.bool_, one_chip)
+    head = (params, specs(eng._caches), specs(eng._pools), i32(S, 1))
+    for fn, rest in [(eng._decode, (flag, key)),
+                     (eng._chunk_fn, (i32(S, T), i32(S), flag, flag, key))]:
+        c = fn.lower(*head, *rest).compile()
+        assert c.memory_analysis().alias_size_in_bytes >= pool_bytes
+        for line in c.as_text().splitlines():
+            m = re.match(r"\s*(?:ROOT )?%\S+ = \w+(\[[\d,]*\])\S* "
+                         r"(copy|select|convolution)\(", line)
+            if not m:
+                continue
+            shape, op = m.groups()
+            assert shape not in shapes, f"pool-sized {op}: {line[:160]}"
+            n = math.prod(int(d) for d in shape[1:-1].split(",") if d)
+            assert op != "convolution" or n not in sizes, line[:160]
