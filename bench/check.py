@@ -20,17 +20,19 @@ def rel_gap(prog: Sequence[float], ref: Sequence[float]) -> float:
     return max(abs(a - b) / abs(b) for a, b in zip(prog, ref))
 
 
-def worst_leaf_gap(prog, ref, keep=None) -> float:
+def leaf_gaps(prog, ref, keep=None) -> np.ndarray:
     """Per node and leaf, the gap between the program's norm and the
     reference's, over the larger of the reference's norm of that leaf and
-    of the node's median leaf; the worst over nodes and leaves. `keep`
-    masks leaves out."""
+    of the node's median leaf; 0 where `keep` masks a leaf out."""
     prog, ref = np.asarray(prog, np.float64), np.asarray(ref, np.float64)
     med = np.median(ref, axis=-1, keepdims=True)
     gap = np.abs(prog - ref) / np.maximum(np.maximum(ref, med), 1e-30)
-    if keep is not None:
-        gap = np.where(keep, gap, 0.0)
-    return float(gap.max())
+    return gap if keep is None else np.where(keep, gap, 0.0)
+
+
+def worst_leaf_gap(prog, ref, keep=None) -> float:
+    """The worst of `leaf_gaps` over nodes and leaves."""
+    return float(leaf_gaps(prog, ref, keep).max())
 
 
 def moving_leaves(grad_norms) -> np.ndarray:

@@ -100,6 +100,25 @@ def load_module(kind: str, name: str, base: Path = BENCH):
     return mod
 
 
+def use_compile_cache(cell: Optional[Cell] = None) -> Path:
+    """Keep JAX's persistent compilation cache in the checkout, at a fixed
+    path. A cell whose job sets `CACHE_EVERY_PROGRAM` keeps every program
+    there, so that only its first run compiles: no size cap (a
+    machine-wide cap evicts a four-chip cell's programs between its runs)
+    and no least compile time (JAX's default of one second leaves out the
+    many small eager compiles of that job's set-up)."""
+    cache = ROOT / ".jax_cache"
+    cache.mkdir(exist_ok=True)
+    os.environ["JAX_COMPILATION_CACHE_DIR"] = str(cache)
+    import jax
+    jax.config.update("jax_compilation_cache_dir", str(cache))
+    if cell is not None and getattr(load_module("jobs", cell.job),
+                                    "CACHE_EVERY_PROGRAM", False):
+        jax.config.update("jax_compilation_cache_max_size", -1)
+        jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
+    return cache
+
+
 def peaks_for(device_kind: str) -> dict:
     table = _json(BENCH / "peaks.json")
     if device_kind not in table:
@@ -243,12 +262,8 @@ def main(argv=None, t_start: Optional[float] = None) -> int:
     except (KeyError, FileNotFoundError) as e:
         print(f"bench: {e}", file=sys.stderr)
         return 2
-    # the compilation cache lives in the checkout, at a fixed path
-    cache = ROOT / ".jax_cache"
-    cache.mkdir(exist_ok=True)
-    os.environ["JAX_COMPILATION_CACHE_DIR"] = str(cache)
+    use_compile_cache(cell)
     import jax
-    jax.config.update("jax_compilation_cache_dir", str(cache))
     devs = jax.devices()
     if devs[0].platform != "tpu":
         print(f"bench: JAX found no accelerator (platform "

@@ -30,10 +30,14 @@ def program_ms(r, module: str) -> Optional[float]:
 
 def collective_ms_per_superstep(r) -> Optional[float]:
     """Device time of the collective permutes, start to done, per chip and
-    per superstep of the window, in ms."""
-    evs = r.trace.ops(r"collective-permute(-start)?", line="async_ops") \
-        or r.trace.ops(r"collective-permute(-done)?")
-    if not evs:
-        return None
-    steps = r.job["readings"]["supersteps"]
-    return 1e3 * sum(e.dur_ns for e in evs) * 1e-9 / r.trace.n_devices / steps
+    per superstep of the window, in ms. The start-to-done spans are on the
+    async line, which the profiler writes for some chips only (one of four
+    on a v5e host); where no chip has one, the synchronous ops stand in.
+    Averaged over the chips that hold such events."""
+    for pattern, line in ((r"collective-permute(-start)?", "async_ops"),
+                          (r"collective-permute(-done)?", "ops")):
+        chips = [evs for evs in r.trace.ops_by_device(pattern, line) if evs]
+        if chips:
+            ns = sum(e.dur_ns for evs in chips for e in evs) / len(chips)
+            return 1e3 * ns * 1e-9 / r.job["readings"]["supersteps"]
+    return None

@@ -77,3 +77,62 @@ def test_op_names():
     assert tr.op_name(KERNEL) == "quantize_mod.3"
     assert tr.base_name(KERNEL) == "quantize_mod"
     assert tr.base_name("jit_decode_masked(1234)") == "jit_decode_masked"
+
+
+def four_chip_trace(async_line=True):
+    """Two supersteps on four chips, as a v5e host's profiler writes them:
+    each chip's synchronous ops, with the permutes' done ops, and the
+    permutes' start-to-done spans on the async line of chip 0 alone."""
+    devices = {}
+    for c in range(4):
+        ops = [E(0, 300, "%fusion.1 = f32[8] fusion()"),
+               E(300 + 10 * c, 20, "%collective-permute-done = u8[8] "
+                 "collective-permute-done(%collective-permute-start)"),
+               E(500, 300 - 50 * c, "%fusion.2 = f32[8] fusion()"),
+               E(800 - 50 * c, 20, "%collective-permute-done.1 = u8[8] "
+                 "collective-permute-done(%collective-permute-start.1)")]
+        starts = [E(250, 70, "%collective-permute-start = (u8[8], u8[8]) "
+                    "collective-permute-start(u8[8] %a)"),
+                  E(700, 120, "%collective-permute-start.1 = (u8[8], u8[8]) "
+                    "collective-permute-start(u8[8] %b)")]
+        devices[f"/device:TPU:{c}"] = tr.DeviceLines(
+            ops=ops, async_ops=starts if async_line and c == 0 else [])
+    return tr.Trace(devices, [E(0, 1000, tr.WINDOW_SPAN)])
+
+
+def _train_readings(trace):
+    from bench.harness import Readings
+    return Readings(None, {"readings": {"supersteps": 2}},
+                    tr.summarize(trace), {})
+
+
+def test_four_chip_idle_is_averaged_over_the_chips():
+    from bench.harness import load_module
+    r = _train_readings(four_chip_trace())
+    # chip c is busy 300 + 20 + (300 - 50c) + 20 ns of the 1000
+    busy = [640 - 50 * c for c in range(4)]
+    assert r.trace.busy_s == pytest.approx(sum(busy) / 4 * 1e-9)
+    assert load_module("metrics", "device_idle.train").read(r) == \
+        pytest.approx(100 * (1 - sum(busy) / 4 / 1000))
+
+
+@pytest.mark.parametrize("async_line", [True, False],
+                         ids=["start_to_done", "done_ops"])
+def test_four_chip_collective_ms_per_chip_and_superstep(async_line):
+    from bench.harness import load_module
+    r = _train_readings(four_chip_trace(async_line))
+    # with the async line: chip 0's spans, 70 + 120 ns over 2 supersteps,
+    # not divided by the three chips that have no such line; without it,
+    # every chip's two 20 ns done ops
+    want_ns = (70 + 120) / 2 if async_line else 2 * 20 / 2
+    assert load_module("metrics", "collective_ms.train").read(r) == \
+        pytest.approx(want_ns * 1e-6)
+
+
+def test_ops_by_device_keeps_each_chip_apart():
+    s = tr.summarize(four_chip_trace())
+    per = s.ops_by_device(r"collective-permute(-done)?")
+    assert [len(evs) for evs in per] == [2, 2, 2, 2]
+    assert [len(evs) for evs in s.ops_by_device(
+        r"collective-permute(-start)?", line="async_ops")] == [2, 0, 0, 0]
+    assert len(s.ops(r"collective-permute(-done)?")) == 8

@@ -11,9 +11,9 @@ process on the chip at the cell's own size:
 Each reading is also judged by the harness's own comparison
 (`bench.check.judge`) against the cell's committed limits.
 
-    python3 bench/tools/calibrate.py --workload wmt-swarm-q8-4chip \
+    python3 bench/tools/calibrate.py --workload olmo-serve-chat \
         --seeds 1,2,3 --control-seeds 1,2,3 \
-        --faults half_batch,no_exchange --fault-seeds 1,2,3
+        --faults altered_token --fault-seeds 1,2,3
 
 Prints one JSON line per reading. Serving cells drive each seed's traffic
 for `--seconds` at the cell's rate, then compare as a run does.
@@ -23,7 +23,6 @@ from __future__ import annotations
 import argparse
 import gc
 import json
-import os
 import sys
 import time
 from pathlib import Path
@@ -46,9 +45,25 @@ def emit(cell, numbers, n_failed=0, **kw):
           flush=True)
 
 
+def worst_leaves(prog, ref, names) -> dict:
+    """Where each norm gap is worst (node, leaf) and its median over the
+    leaves: which leaf sets a reading, and whether the others agree."""
+    import numpy as np
+    from bench import check
+    out = {}
+    for key, keep in (("mom_norms", None),
+                      ("change_norms", check.moving_leaves(ref["grad_norms"]))):
+        gap = check.leaf_gaps(prog[key], ref[key], keep)
+        node, leaf = np.unravel_index(int(np.argmax(gap)), gap.shape)
+        out[key[:-6]] = {"worst": [int(node), names[leaf]],
+                         "median": float(np.median(gap))}
+    return out
+
+
 def train(cell, devices, args):
+    import jax
     from bench import check, faults
-    from bench.jobs.train import Swarm
+    from bench.jobs.train import BUILD_SEED, Swarm
     refs = {}
 
     def reference(sw, seed):
@@ -57,7 +72,9 @@ def train(cell, devices, args):
             refs[seed] = sw.reference()
         return refs[seed]
 
-    sw = Swarm(cell, devices, args.seeds[0])
+    sw = Swarm(cell, devices, BUILD_SEED)
+    names = [jax.tree_util.keystr(k) for k, _ in
+             jax.tree_util.tree_flatten_with_path(sw.shapes)[0]]
     for seed in args.seeds:
         sw.reseed(seed)
         if sw.compiled is None:
@@ -67,19 +84,20 @@ def train(cell, devices, args):
         ref = reference(sw, seed)
         emit(cell, check.train_numbers(prog, ref), kind="program", seed=seed,
              losses=prog["losses"], ref_losses=ref["losses"],
-             wall_s=time.time() - t)
+             wall_s=time.time() - t, **worst_leaves(prog, ref, names))
     for seed in args.control_seeds:
         sw.reseed(seed)
         sw.free()
         ref = reference(sw, seed)
         ctrl = sw.reference("fp8")
         emit(cell, check.train_numbers(ctrl, ref), kind="control", seed=seed,
-             losses=ctrl["losses"], ref_losses=ref["losses"])
+             losses=ctrl["losses"], ref_losses=ref["losses"],
+             **worst_leaves(ctrl, ref, names))
     del sw
     gc.collect()
     for name in args.faults:
         with faults.FAULTS[name]():
-            fsw = Swarm(cell, devices, args.seeds[0])   # same pool
+            fsw = Swarm(cell, devices, BUILD_SEED)   # same pool
             for seed in args.fault_seeds:
                 fsw.reseed(seed)
                 if fsw.compiled is None:
@@ -87,7 +105,9 @@ def train(cell, devices, args):
                 prog = fsw.first_steps()
                 ref = reference(fsw, seed)
                 emit(cell, check.train_numbers(prog, ref),
-                     kind=f"fault:{name}", seed=seed, losses=prog["losses"], ref_losses=ref["losses"])
+                     kind=f"fault:{name}", seed=seed, losses=prog["losses"],
+                     ref_losses=ref["losses"],
+                     **worst_leaves(prog, ref, names))
             fsw.free()
             del fsw
             gc.collect()
@@ -152,11 +172,10 @@ def main(argv=None) -> int:
     ap.add_argument("--fault-seeds", type=_ints, default=[])
     ap.add_argument("--seconds", type=float, default=10.0)
     args = ap.parse_args(argv)
-    (ROOT / ".jax_cache").mkdir(exist_ok=True)
-    os.environ["JAX_COMPILATION_CACHE_DIR"] = str(ROOT / ".jax_cache")
-    import jax
-    from bench.harness import load_cell
+    from bench.harness import load_cell, use_compile_cache
     cell = load_cell(args.workload)
+    use_compile_cache(cell)
+    import jax
     devs = jax.devices()
     if devs[0].platform != "tpu" or len(devs) < cell.chips:
         print("calibrate: needs the chips the cell asks for", file=sys.stderr)

@@ -135,10 +135,9 @@ def main(argv=None) -> int:
                     help="0: trace without the profiler's Python function "
                     "tracer, which bench/run.py leaves on")
     args = ap.parse_args(argv)
-    (ROOT / ".jax_cache").mkdir(exist_ok=True)
-    os.environ["JAX_COMPILATION_CACHE_DIR"] = str(ROOT / ".jax_cache")
+    from bench.harness import use_compile_cache
+    use_compile_cache()
     import jax
-    jax.config.update("jax_compilation_cache_dir", str(ROOT / ".jax_cache"))
     if not args.python_tracer:
         opts = jax.profiler.ProfileOptions()
         opts.python_tracer_level = 0

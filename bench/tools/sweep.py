@@ -14,7 +14,6 @@ from __future__ import annotations
 
 import argparse
 import json
-import os
 import sys
 import time
 from pathlib import Path
@@ -31,10 +30,9 @@ def main(argv=None) -> int:
     ap.add_argument("--seconds", type=float, default=30.0)
     ap.add_argument("--seed", type=int, default=7)
     args = ap.parse_args(argv)
-    (ROOT / ".jax_cache").mkdir(exist_ok=True)
-    os.environ["JAX_COMPILATION_CACHE_DIR"] = str(ROOT / ".jax_cache")
+    from bench.harness import load_cell, use_compile_cache
+    use_compile_cache()
     import jax
-    from bench.harness import load_cell
     from bench.jobs.serve import Server, window_stats
     from bench.stats import percentile
     from bench.traffic import chat_requests

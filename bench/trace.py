@@ -220,12 +220,17 @@ class Summary:
     def idle_share(self) -> float:
         return 1.0 - self.busy_s / self.window_s
 
+    def ops_by_device(self, pattern: str,
+                      line: str = "ops") -> List[List[Event]]:
+        """Per device, its window events whose op base name matches."""
+        rx = re.compile(pattern)
+        return [[e for e in in_window(getattr(d, line), self.lo, self.hi)
+                 if rx.fullmatch(base_name(e.name))]
+                for d in self.trace.devices.values()]
+
     def ops(self, pattern: str, line: str = "ops") -> List[Event]:
         """Window events of every device whose op base name matches."""
-        rx = re.compile(pattern)
-        return [e for d in self.trace.devices.values()
-                for e in in_window(getattr(d, line), self.lo, self.hi)
-                if rx.fullmatch(base_name(e.name))]
+        return [e for evs in self.ops_by_device(pattern, line) for e in evs]
 
     def breakdown(self, top: int = 10) -> dict:
         """Longest device operations (seconds summed over the window,
