@@ -4,10 +4,26 @@ A cell is an entry of `workloads` in BENCHMARK.json: a configuration and
 a traffic mix on 1 or 4 chips. Everything else is found by name:
 
     bench/configs/<config>.json    model, deployment, source, cuts
+    bench/reference/<module>.py    the configuration's plain reference,
+                                   named by its `reference.module`
     bench/traffic/<traffic>.json   the job and its traffic parameters
     bench/limits/<cell>.json       the limit of each number compared
     bench/jobs/<job>.py            run(ctx) -> result of one run
     bench/metrics/<metric>.py      read(r) -> a per-layer metric, or None
+
+A reference module imports nothing of the program and offers, with
+`model` the configuration's own `model` dict and `prec` "f32" or "fp8"
+(the control):
+
+    init_params(key, shapes, dtype, **config.get("weights", {}))
+    hidden(model, params, tokens, prec)     final normed hidden states
+    logits(model, params, h, prec)          logits of hidden states
+    loss_and_grad(model, params, tokens, targets, micro, prec)
+                                            training only
+
+A job lists the functions it calls in `REFERENCE_NEEDS`; a cell whose
+module is missing, or lacks one of them, is refused when it is loaded
+(exit 2, before set-up).
 
 The harness checks the device (a TPU, as many chips as the cell asks
 for, listed in bench/peaks.json), keeps JAX's compilation cache in
@@ -46,6 +62,7 @@ class Cell:
     limits: dict
     end_to_end: List[dict]
     per_layer: List[dict]
+    reference: Any              # the module bench/reference/<module>.py
 
     @property
     def job(self) -> str:
@@ -58,7 +75,8 @@ def _json(path: Path) -> Any:
 
 
 def load_cell(name: str, root: Path = ROOT) -> Cell:
-    """The cell `name` of `<root>/BENCHMARK.json` with its data files."""
+    """The cell `name` of `<root>/BENCHMARK.json` with its data files and
+    its configuration's reference module."""
     bench = _json(Path(root) / "BENCHMARK.json")
     cells = {w["name"]: w for w in bench["workloads"]}
     if name not in cells:
@@ -72,27 +90,66 @@ def load_cell(name: str, root: Path = ROOT) -> Cell:
     per_layer = [m for m in bench["per_layer"]
                  if name in m.get("workloads", [name] if m["moves"] in moved
                                   else [])]
-    return Cell(name=name, chips=int(w["chips"]),
-                config=_json(data / "configs" / f"{w['config']}.json"),
-                traffic=_json(data / "traffic" / f"{w['traffic']}.json"),
+    config = _json(data / "configs" / f"{w['config']}.json")
+    traffic = _json(data / "traffic" / f"{w['traffic']}.json")
+    needs = getattr(load_module("jobs", traffic["job"]), "REFERENCE_NEEDS",
+                    ())
+    return Cell(name=name, chips=int(w["chips"]), config=config,
+                traffic=traffic,
                 limits=_json(data / "limits" / f"{name}.json"),
-                end_to_end=e2e, per_layer=per_layer)
+                end_to_end=e2e, per_layer=per_layer,
+                reference=load_reference(config, needs, data))
+
+
+def load_reference(config: dict, needs=(), base: Path = BENCH):
+    """The reference module `<base>/reference/<module>.py` that the
+    configuration names (`reference.module`), holding each of `needs`.
+    Both jobs, and the tools, take their reference from here."""
+    name = config["reference"]["module"]
+    mod = load_module("reference", name, base)
+    missing = [f for f in needs if not callable(getattr(mod, f, None))]
+    if missing:
+        raise AttributeError(f"reference {name!r} lacks {missing}")
+    return mod
 
 
 def model_config(conf: dict):
     """The program's registered architecture (`arch`) with the
     configuration's `model` keys applied: a no-op at the published
-    sizes, the configuration as it is run in any case."""
+    sizes, the configuration as it is run in any case. A dict under a
+    sub-config key (`moe`, `ssm`, `frontend`) is applied to the
+    architecture's sub-config, or builds one where it has none; a
+    `pattern` list becomes a tuple of (mixer, ffn) tuples. A key that
+    is not a field is an error that names it."""
     import dataclasses
-    from repro.configs import get_config
-    return dataclasses.replace(get_config(conf["arch"]), **conf["model"])
+    from repro.configs import (FrontendConfig, MoEConfig, SSMConfig,
+                               get_config)
+    subs = {"moe": MoEConfig, "ssm": SSMConfig, "frontend": FrontendConfig}
+    base = get_config(conf["arch"])
+
+    def unknown(keys, cls, where):
+        bad = sorted(set(keys) - {f.name for f in dataclasses.fields(cls)})
+        if bad:
+            raise ValueError(f"model key {where}{bad[0]!r} is not a field "
+                             f"of {cls.__name__} ({conf['arch']})")
+    unknown(conf["model"], type(base), "")
+    changes = {}
+    for k, v in conf["model"].items():
+        if k in subs and isinstance(v, dict):
+            unknown(v, subs[k], f"{k}.")
+            sub = getattr(base, k)
+            v = subs[k](**v) if sub is None else dataclasses.replace(sub, **v)
+        elif k == "pattern":
+            v = tuple(tuple(layer) for layer in v)
+        changes[k] = v
+    return dataclasses.replace(base, **changes)
 
 
 def load_module(kind: str, name: str, base: Path = BENCH):
     """`<base>/<kind>/<name>.py` as a module (names may hold dots)."""
     path = Path(base) / kind / f"{name}.py"
     if not path.is_file():
-        raise FileNotFoundError(f"no {kind[:-1]} {name!r} at {path}")
+        raise FileNotFoundError(f"no {kind.rstrip('s')} {name!r} at {path}")
     spec = importlib.util.spec_from_file_location(
         f"bench_{kind}_{name.replace('.', '_').replace('-', '_')}", path)
     mod = importlib.util.module_from_spec(spec)
@@ -259,7 +316,7 @@ def main(argv=None, t_start: Optional[float] = None) -> int:
     sys.path.insert(0, str(src))
     try:
         cell = load_cell(args.workload)
-    except (KeyError, FileNotFoundError) as e:
+    except (KeyError, FileNotFoundError, AttributeError) as e:
         print(f"bench: {e}", file=sys.stderr)
         return 2
     use_compile_cache(cell)

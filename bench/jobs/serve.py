@@ -17,9 +17,10 @@ after a warm-up at the same rate, and closes after `--seconds`.
 
 After the window no request is sent; the engine finishes those due in it
 (for at most `drain_s`). A sample of them drawn from the seed, with the
-longest, is then replayed through the plain float32 reference: the gap
-by which each served token's reference logit lies below the reference's
-best is the number compared.
+longest, is then replayed through the plain float32 reference that the
+configuration names (bench/reference): the gap by which each served
+token's reference logit lies below the reference's best is the number
+compared.
 """
 from __future__ import annotations
 
@@ -34,6 +35,7 @@ from bench.trace import span
 from bench.traffic import chat_requests, seed32
 
 MISSING_MS = 3.6e6          # a request never served counts as an hour
+REFERENCE_NEEDS = ("init_params", "hidden", "logits")
 
 
 class Server:
@@ -44,15 +46,14 @@ class Server:
         import jax.numpy as jnp
         from repro.models import init_params
         from repro.serve.engine import EngineConfig, ServeEngine
-        from bench.reference import transformer as ref_t
         self.cfg = cfg = model_config(cell.config)
         self.ecfg = EngineConfig(**cell.config["deployment"]["engine"])
         shapes = jax.eval_shape(
             lambda: init_params(jax.random.PRNGKey(0), cfg))
         dtype = jnp.dtype(cfg.dtype)
-        embed_std = cell.config["weights"]["embed_std"]
+        ref, weights = cell.reference, cell.config.get("weights", {})
         self.make_weights = jax.jit(
-            lambda key: ref_t.init_params(key, shapes, dtype, embed_std),
+            lambda key: ref.init_params(key, shapes, dtype, **weights),
             out_shardings=jax.sharding.SingleDeviceSharding(device))
         self.seed = seed
         self.engine = ServeEngine(cfg, self.ecfg, params=self.weights())
@@ -166,15 +167,16 @@ def sample_for_check(d: dict, seed: int, min_tokens: int) -> list:
     return out
 
 
-def served_gaps(model: dict, params, sample, d: dict, pad_to: int,
+def served_gaps(ref, model: dict, params, sample, d: dict, pad_to: int,
                 rows: int, prec: str = "f32") -> np.ndarray:
     """For each sampled request, replay prompt + served tokens through the
-    reference; per served token, how far its reference logit lies below
-    the reference's best. With prec="fp8" the control: the gap of the
-    token the lower-precision reference puts first."""
+    reference module `ref` with the configuration's `model`; per served
+    token, how far its reference logit lies below the reference's best.
+    With prec="fp8" the control: the gap of the token the lower-precision
+    reference puts first."""
     import jax
     from bench.reference.serve import gap_fn
-    fn = gap_fn(tuple(sorted(model.items())), pad_to, rows, prec)
+    fn = gap_fn(ref, model, pad_to, rows, prec)
     out = []
     for k in sample:
         prompt = d["requests"][k][1]
@@ -205,8 +207,9 @@ def run(ctx) -> dict:
     ref_cfg = cell.config["reference"]
     sample = sample_for_check(d, ctx.seed, ref_cfg["check_tokens"])
     t_ref = time.time()
-    gaps = served_gaps(cell.config["model"], srv.weights(), sample, d,
-                       srv.ecfg.kv_capacity, srv.ecfg.max_new_tokens)
+    gaps = served_gaps(cell.reference, cell.config["model"], srv.weights(),
+                       sample, d, srv.ecfg.kv_capacity,
+                       srv.ecfg.max_new_tokens)
     ctx.phase(f"reference {time.time() - t_ref:.3f}s, after window")
     return {
         "setup_s": d["t_w0"] - ctx.t_start,

@@ -16,9 +16,9 @@ after the last. `train_tokens_per_s` is every token that every node's
 local steps consumed in the window, over the window.
 
 Once the window has closed and the program's state is freed, the plain
-reference (bench/reference) follows the same three supersteps from the
-same weights, batches and pairings, and the numbers of bench/check.py
-compare the two.
+reference that the configuration names (bench/reference) follows the
+same three supersteps from the same weights, batches and pairings, and
+the numbers of bench/check.py compare the two.
 """
 from __future__ import annotations
 
@@ -42,6 +42,7 @@ TIMED_STEPS = 2
 BUILD_SEED = 0
 # its set-up makes dozens of small eager compiles (bench.harness)
 CACHE_EVERY_PROGRAM = True
+REFERENCE_NEEDS = ("init_params", "loss_and_grad")
 
 
 def _matching_pairs(perm) -> list:
@@ -63,7 +64,6 @@ class Swarm:
         from repro.core.graph import make_graph
         from repro.launch.mesh import node_mesh
         from repro.launch.train import build_trainer
-        from bench.reference import transformer as ref_t
 
         self.cell, self.devices = cell, devices
         conf, self.traffic = cell.config, cell.traffic
@@ -103,8 +103,9 @@ class Swarm:
         dtype = jnp.dtype(self.cfg.dtype)
         n = self.n_nodes
         shp = self.shapes
+        ref, weights = cell.reference, conf.get("weights", {})
         self.make_weights = jax.jit(
-            lambda key: ref_t.init_params(key, shp, dtype))
+            lambda key: ref.init_params(key, shp, dtype, **weights))
 
         def stacked(p):
             tree = jax.tree.map(
@@ -212,7 +213,8 @@ class Swarm:
     def reference(self, prec: str = "f32") -> dict:
         from bench.reference import swarm as ref_swarm
         return ref_swarm.run(
-            self.model, self.weights(self.seed), self.ring[:FIRST_STEPS],
+            self.cell.reference, self.model, self.weights(self.seed),
+            self.ring[:FIRST_STEPS],
             [self.pairs(t) for t in range(FIRST_STEPS)],
             lr=self.dep["lr"], mu=self.dep["momentum"], devices=self.devices,
             micro=self.cell.config["reference"]["micro_batch"], prec=prec)

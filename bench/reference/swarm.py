@@ -13,12 +13,13 @@ run side by side.
 """
 from __future__ import annotations
 
+import json
 from functools import partial
 
 import jax
 import jax.numpy as jnp
 
-from bench.reference import transformer as T
+from bench.reference import model_key
 
 F32 = jnp.float32
 
@@ -29,16 +30,17 @@ def leaf_norms(tree) -> jnp.ndarray:
                       for x in jax.tree.leaves(tree)])
 
 
-@partial(jax.jit, static_argnames=("model", "micro", "prec", "lr", "mu"))
-def _local_steps(params, mom, tokens, targets, *, model, micro, prec, lr,
-                 mu):
+@partial(jax.jit,
+         static_argnames=("ref", "model", "micro", "prec", "lr", "mu"))
+def _local_steps(params, mom, tokens, targets, *, ref, model, micro, prec,
+                 lr, mu):
     """H steps on one node's [H, B, S] batch: (params, momentum, mean loss
     over the steps, leaf norms of the first gradient)."""
-    mdl = dict(model)
+    mdl = json.loads(model)
     losses, g1 = [], None
     for q in range(tokens.shape[0]):
-        l, g = T.loss_and_grad(mdl, params, tokens[q], targets[q], micro,
-                               prec)
+        l, g = ref.loss_and_grad(mdl, params, tokens[q], targets[q], micro,
+                                 prec)
         if g1 is None:
             g1 = leaf_norms(g)
         mom = jax.tree.map(lambda m, gg: mu * m + gg, mom, g)
@@ -60,16 +62,18 @@ def _change_norms(p, p0):
                                    p, p0))
 
 
-def run(model: dict, params0, batches, pairs, *, lr: float, mu: float,
+def run(ref, model: dict, params0, batches, pairs, *, lr: float, mu: float,
         devices, micro: int, prec: str = "f32") -> dict:
-    """Follow len(batches) supersteps from `params0` (one node's tree, the
-    same for every node). `batches[t]` holds numpy {"tokens", "targets"}
-    of shape [n_nodes, H, B, S]; `pairs[t]` the matched node pairs of
-    superstep t. Returns per-superstep mean losses, and per node and leaf:
-    the momentum norms after the first superstep, the first gradient's
-    norms, and the norms of the parameters' change after the last."""
+    """Follow len(batches) supersteps of the reference module `ref` (see
+    bench/reference) with the configuration's `model` dict from `params0`
+    (one node's tree, the same for every node). `batches[t]` holds numpy
+    {"tokens", "targets"} of shape [n_nodes, H, B, S]; `pairs[t]` the
+    matched node pairs of superstep t. Returns per-superstep mean losses,
+    and per node and leaf: the momentum norms after the first superstep,
+    the first gradient's norms, and the norms of the parameters' change
+    after the last."""
     n = batches[0]["tokens"].shape[0]
-    mdl = tuple(sorted(model.items()))
+    mdl = model_key(model)
     dev = [devices[i % len(devices)] for i in range(n)]
     p = [jax.device_put(params0, dev[i]) for i in range(n)]
     m = [jax.tree.map(lambda x: jnp.zeros(x.shape, F32), p[i])
@@ -80,8 +84,8 @@ def run(model: dict, params0, batches, pairs, *, lr: float, mu: float,
         for i in range(n):
             out.append(_local_steps(
                 p[i], m[i], jax.device_put(batch["tokens"][i], dev[i]),
-                jax.device_put(batch["targets"][i], dev[i]), model=mdl,
-                micro=micro, prec=prec, lr=lr, mu=mu))
+                jax.device_put(batch["targets"][i], dev[i]), ref=ref,
+                model=mdl, micro=micro, prec=prec, lr=lr, mu=mu))
             p[i] = m[i] = None
             if len(set(dev)) < n:       # nodes share a chip: one at a time
                 jax.block_until_ready(out[-1])

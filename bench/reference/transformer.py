@@ -38,9 +38,10 @@ def _leaf_name(path) -> str:
 
 def init_params(key, shapes, dtype, embed_std: float = 0.02):
     """Weights for the given layout from one key: norm scales 1, norm
-    biases 0, the embedding N(0, embed_std^2), every other matrix
-    N(0, 1/fan_in) with fan_in its second-to-last axis. Jit it: the
-    weights are then made on the device in one call."""
+    biases 0, the embedding N(0, embed_std^2) (the configuration's
+    `weights.embed_std`), every other matrix N(0, 1/fan_in) with fan_in
+    its second-to-last axis. Jit it: the weights are then made on the
+    device in one call."""
     flat, treedef = jax.tree_util.tree_flatten_with_path(shapes)
     keys = jax.random.split(key, len(flat))
     out = []

@@ -88,7 +88,8 @@ def test_control_is_caught(cell):
     srv.free()
     sample = sample_for_check(d, SEED, cell.config["reference"]
                               ["check_tokens"])
-    g = served_gaps(cell.config["model"], srv.weights(), sample, d,
-                    srv.ecfg.kv_capacity, srv.ecfg.max_new_tokens, "fp8")
+    g = served_gaps(cell.reference, cell.config["model"], srv.weights(),
+                    sample, d, srv.ecfg.kv_capacity, srv.ecfg.max_new_tokens,
+                    "fp8")
     ok, checks = check.judge({"logit_gap": float(g.max())}, cell.limits, 0)
     assert not ok, checks

@@ -53,22 +53,48 @@ def test_names_and_paths_keep_to_the_contract():
         assert 0.01 <= e["bound"] <= 0.25
 
 
-def test_a_new_cell_is_found_by_name(tmp_path):
-    """A cell added as files only: a configuration, a traffic mix and
-    limits, plus its entry in BENCHMARK.json."""
+# a configuration of another registered architecture: a hybrid of Mamba-2
+# and attention layers with experts, whose nested keys and `pattern`
+# list resolve to the program's dataclasses, with a reference of its own
+HYBRID = {
+    "arch": "jamba-1.5-large-398b",
+    "model": {"n_layers": 4, "d_model": 64, "n_heads": 4, "n_kv_heads": 2,
+              "head_dim": 16, "d_ff": 128, "vocab_size": 256,
+              "pattern": [["mamba", "moe"], ["attn", "moe"]],
+              "moe": {"n_experts": 4, "top_k": 2, "d_ff": 32},
+              "ssm": {"d_state": 16, "head_dim": 16, "chunk": 8}},
+    "reference": {"module": "hybrid-x", "check_tokens": 40}}
+STUB_REFERENCE = """
+def init_params(key, shapes, dtype, **weights):
+    raise NotImplementedError
+
+
+hidden = logits = init_params
+"""
+
+
+@pytest.mark.parametrize("conf_name", ["olmo-x", "hybrid-x"])
+def test_a_new_cell_is_found_by_name(tmp_path, conf_name):
+    """A cell added as files only: a configuration (with its reference,
+    where it brings one), a traffic mix and limits, plus its entry in
+    BENCHMARK.json."""
     b = bench_json()
-    b["workloads"].append({"name": "olmo-serve-overload", "config": "olmo-x",
-                           "traffic": "chat-overload", "chips": 1,
-                           "why": "above the knee"})
+    b["workloads"].append({"name": "olmo-serve-overload",
+                           "config": conf_name, "traffic": "chat-overload",
+                           "chips": 1, "why": "above the knee"})
     for m in b["end_to_end"] + b["per_layer"]:
         if "olmo-serve-chat" in m.get("workloads", []):
             m["workloads"].append("olmo-serve-overload")
     (tmp_path / "bench").mkdir()
-    for d in ("configs", "traffic", "limits"):
+    for d in ("configs", "traffic", "limits", "reference"):
         shutil.copytree(BENCH / d, tmp_path / "bench" / d)
     conf = json.loads((BENCH / "configs" / "olmo-1b.json").read_text())
-    conf["name"] = "olmo-x"
-    (tmp_path / "bench/configs/olmo-x.json").write_text(json.dumps(conf))
+    conf["name"] = conf_name
+    if conf_name == "hybrid-x":
+        conf.update(HYBRID)
+        (tmp_path / "bench/reference/hybrid-x.py").write_text(STUB_REFERENCE)
+    (tmp_path / f"bench/configs/{conf_name}.json").write_text(
+        json.dumps(conf))
     chat = json.loads((BENCH / "traffic" / "chat-poisson.json").read_text())
     chat["rate_rps"] *= 1.5
     (tmp_path / "bench/traffic/chat-overload.json").write_text(
@@ -77,7 +103,17 @@ def test_a_new_cell_is_found_by_name(tmp_path):
         json.dumps({"logit_gap": 1.0}))
     (tmp_path / "BENCHMARK.json").write_text(json.dumps(b))
     cell = harness.load_cell("olmo-serve-overload", tmp_path)
-    assert cell.config["name"] == "olmo-x" and cell.job == "serve"
+    assert cell.config["name"] == conf_name and cell.job == "serve"
+    module = conf["reference"]["module"]
+    assert cell.reference.__file__ == str(
+        tmp_path / "bench" / "reference" / f"{module}.py")
+    cfg = harness.model_config(cell.config)
+    assert cfg.n_layers == conf["model"]["n_layers"]
+    if conf_name == "hybrid-x":
+        assert cfg.pattern == (("mamba", "moe"), ("attn", "moe"))
+        assert (cfg.moe.n_experts, cfg.moe.top_k, cfg.moe.d_ff) == (4, 2, 32)
+        assert (cfg.ssm.d_state, cfg.ssm.head_dim, cfg.ssm.chunk) == \
+            (16, 16, 8)
     assert cell.traffic["rate_rps"] == chat["rate_rps"]
     assert {m["name"] for m in cell.end_to_end} == {
         "serve_tokens_per_s", "ttft_p95_ms", "itl_p95_ms", "setup_s"}
@@ -98,7 +134,7 @@ def test_a_metric_without_workloads_follows_what_it_moves(tmp_path):
                             "better": "higher", "bound": 0.05,
                             "source": "host_clock", "workloads": ["wmt-x"]})
     (tmp_path / "bench").mkdir()
-    for d in ("configs", "traffic", "limits"):
+    for d in ("configs", "traffic", "limits", "reference"):
         shutil.copytree(BENCH / d, tmp_path / "bench" / d)
     (tmp_path / "bench/limits/wmt-x.json").write_text('{"loss_gap": 1}')
     (tmp_path / "BENCHMARK.json").write_text(json.dumps(b))

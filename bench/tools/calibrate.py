@@ -5,7 +5,8 @@ process on the chip at the cell's own size:
   largest of them);
 * the control's on each of `--control-seeds`: the reference itself put in
   the program's place, computed in float8 (the upper reading is the
-  smallest);
+  smallest). The reference is the module the configuration names, as
+  `bench.harness.load_cell` finds it for a run;
 * each planted fault's (bench/faults.py) on each of `--fault-seeds`.
 
 Each reading is also judged by the harness's own comparison
@@ -120,7 +121,7 @@ def serve(cell, devices, args):
                                   window_stats)
     from bench.traffic import chat_requests
     tr = cell.traffic
-    model = cell.config["model"]
+    ref, model = cell.reference, cell.config["model"]
 
     def one(seed, kinds):
         srv = Server(cell, devices[0], seed)
@@ -135,11 +136,12 @@ def serve(cell, devices, args):
         w = srv.weights()
         for kind in kinds:
             prec = "fp8" if kind == "control" else "f32"
-            g = served_gaps(model, w, sample, d, srv.ecfg.kv_capacity,
+            g = served_gaps(ref, model, w, sample, d, srv.ecfg.kv_capacity,
                             srv.ecfg.max_new_tokens, prec)
             extra = {}
             if kind == "program":
-                m = served_gaps(model, w, sample, d, srv.ecfg.kv_capacity,
+                m = served_gaps(ref, model, w, sample, d,
+                                srv.ecfg.kv_capacity,
                                 srv.ecfg.max_new_tokens, "margin")
                 extra = {f"margin_p{q}": float(np.percentile(m, q))
                          for q in (1, 10, 50)}
