@@ -1157,7 +1157,7 @@ def t15_serve(quick=False):
     # blocking admission vs chunked prefill. The latency series is the
     # per-lane inter-commit gap, so a blocking prefill that stalls every
     # live decode lane lands in the tail; chunked prefill interleaves one
-    # [slots, T] chunk per engine step and must STRICTLY cut p99.
+    # [P, T] chunk per engine step and must STRICTLY cut p99.
     acfg = reduced(get_config("olmo-1b"), n_layers=2, d_model=64)
     aparams = init_params(k_a, acfg)
     n_burst = 8 if quick else 12

@@ -3,8 +3,9 @@
 Slot-based scheduling over the repo's prefill/decode fns: sequences join
 and retire MID-BATCH by flipping a lane mask — the same masking
 discipline the training engine uses for churn (core/swarm.py): every
-lane computes every step, only masked lanes COMMIT, so all shapes are
-static and each serving fn compiles exactly once.
+lane computes every decode step, only masked lanes COMMIT, so all shapes
+are static and the decode fn compiles exactly once. Prefill chunks
+compute only the lanes that prefill, in a bucket of fixed width (below).
 
 KV memory comes in two layouts:
 
@@ -25,11 +26,16 @@ Prefill comes in two schedules:
   lanes for the full prompt (head-of-line blocking). Ragged prompts
   dispatch at their own length (one compile per distinct length).
 * chunked (``prefill_chunk`` > 0 / REPRO_PREFILL_CHUNK): prompts prefill
-  in fixed-shape [slots, T] token chunks, one chunk dispatch interleaved
-  with the decode dispatch per engine step, masked commits — ragged
-  prompts are length-masked chunks and NOTHING recompiles. Decode lanes
-  keep committing tokens while prompts prefill, which is what flattens
-  in-flight p99 under bursts (benchmarks t15).
+  in [P, T] token chunks, one chunk dispatch interleaved with the decode
+  dispatch per engine step. P, the bucket, is the smallest power of two
+  at least the number of prefilling lanes, capped at (and always
+  including) `max_slots`: the dispatch gathers those lanes' state out of
+  the bank, computes them alone and scatters back what they commit;
+  padding rows write nothing. Ragged prompts are length-masked chunks;
+  the first chunk dispatch compiles every bucket width, so NOTHING
+  compiles after it. Decode lanes keep committing tokens while prompts
+  prefill, which is what flattens in-flight p99 under bursts
+  (benchmarks t15).
 
 Hot swap (serve/swap.py) composes with the batch through generations: a
 lane is pinned to the param generation it was ADMITTED under and finishes
@@ -87,6 +93,16 @@ def grow_cache(full, cache):
                 f"{dst.shape}")
         return dst.at[tuple(slice(0, s) for s in src.shape)].set(src)
     return jax.tree_util.tree_map_with_path(grow, full, cache)
+
+
+def chunk_widths(slots: int) -> List[int]:
+    """The chunk dispatch's bucket widths: the powers of two below
+    `slots`, then `slots` itself (1, 2, 4, 8, 16 for 16 slots)."""
+    widths, w = [], 1
+    while w < slots:
+        widths.append(w)
+        w *= 2
+    return widths + [slots]
 
 
 def _env_flag(name: str) -> bool:
@@ -206,6 +222,8 @@ class ServeEngine:
         self._pools = P.build_pools(cfg, ecfg.pool_pages, ecfg.page_size,
                                     dtype) if self._paged else None
         self._build_fns()
+        self._chunk_widths = chunk_widths(ecfg.max_slots)
+        self._chunks_compiled = False
         self._caches = self._init_cache_bank()
         self._tokens = jnp.zeros((ecfg.max_slots, 1), jnp.int32)
         self.metrics.kv_pool_pages = ecfg.pool_pages if self._paged else 0
@@ -218,7 +236,7 @@ class ServeEngine:
             self.swap.publish(params, t_landed=time.time(), tag="init")
         install_gc_spans()
 
-    # -- compiled serving fns (each compiles exactly once) -----------------
+    # -- compiled serving fns (each compiles once; the chunk once a width) --
 
     def _build_fns(self):
         cfg, ecfg = self.cfg, self.ecfg
@@ -254,12 +272,6 @@ class ServeEngine:
             return out, jax.lax.dynamic_update_index_in_dim(
                 tokens, tok[None], i, 0)
 
-        def sel_commit(commit):
-            def sel(new, old):
-                m = commit.reshape((-1,) + (1,) * (new.ndim - 1))
-                return jnp.where(m, new, old)
-            return sel
-
         def decode_masked(params, caches, pools, tokens, commit, key):
             """One decode step over ALL lanes; only `commit` lanes commit
             their cache/token updates (masking discipline = churn)."""
@@ -272,8 +284,10 @@ class ServeEngine:
             keys = jax.random.split(key, ecfg.max_slots)
             toks = jax.vmap(sample)(logits, keys)               # [slots]
             new_caches, rows = P.split_new_rows(new_caches)
-            caches_out = jax.tree.map(sel_commit(commit), new_caches,
-                                      caches)
+            caches_out = jax.tree.map(
+                lambda new, old: jnp.where(
+                    commit.reshape((-1,) + (1,) * (new.ndim - 1)), new, old),
+                new_caches, caches)
             if rows is not None:
                 pools = P.scatter_tree(
                     pools, rows, caches["pages"], caches["len"],
@@ -282,11 +296,16 @@ class ServeEngine:
             return toks_out, caches_out, pools
 
         def chunk_masked(params, caches, pools, tokens, chunks, n_valid,
-                         commit, finish, key):
-            """One [slots, T] prefill-chunk step; `commit` lanes advance
-            their caches by n_valid tokens, `finish` lanes (final chunk)
+                         commit, finish, lanes, key):
+            """One [P, T] prefill-chunk step over the bank lanes `lanes`
+            ([P] slot indices; a padding row holds the sentinel
+            `max_slots` with n_valid 0 and commit/finish False). The rows
+            are gathered out of the lane bank (a padding row reads the
+            last lane, clipped) and computed alone; `commit` rows scatter
+            their caches advanced by n_valid tokens back into their lanes
+            and their KV rows into the pools, `finish` rows (final chunk)
             also commit the prompt's next-token sample as their first
-            generated token."""
+            generated token. No other lane is read or written."""
             def one(cache, toks, nv):
                 hidden, c2, _ = forward(cfg, params, toks[None, :],
                                         mode="chunk", cache=cache,
@@ -294,16 +313,24 @@ class ServeEngine:
                 last = jax.lax.dynamic_slice_in_dim(
                     hidden, jnp.maximum(nv - 1, 0), 1, axis=1)
                 return logits_head(cfg, params, last)[0, -1], c2
-            logits, new_caches = jax.vmap(one)(caches, chunks, n_valid)
-            keys = jax.random.split(key, ecfg.max_slots)
+            slots = ecfg.max_slots
+            sub = jax.tree.map(
+                lambda x: jnp.take(x, lanes, axis=0, mode="clip"), caches)
+            logits, new_caches = jax.vmap(one)(sub, chunks, n_valid)
+            # each lane samples with the key it had when every lane ran
+            keys = jax.random.split(key, slots)[
+                jnp.minimum(lanes, slots - 1)]
             toks = jax.vmap(sample)(logits, keys)
             new_caches, rows = P.split_new_rows(new_caches)
-            caches_out = jax.tree.map(sel_commit(commit), new_caches,
-                                      caches)
+            dst = jnp.where(commit, lanes, slots)          # slots: dropped
+            caches_out = jax.tree.map(
+                lambda bank, new: bank.at[dst].set(new, mode="drop"),
+                caches, new_caches)
             if rows is not None:
-                pools = P.scatter_tree(pools, rows, caches["pages"],
-                                       caches["len"], n_valid, commit, page)
-            toks_out = jnp.where(finish, toks, tokens[:, 0])[:, None]
+                pools = P.scatter_tree(pools, rows, sub["pages"],
+                                       sub["len"], n_valid, commit, page)
+            toks_out = tokens.at[jnp.where(finish, lanes, slots)].set(
+                toks[:, None], mode="drop")
             return toks_out, caches_out, pools
 
         def reset_lane(caches, i):
@@ -493,47 +520,74 @@ class ServeEngine:
         self.metrics.completed += 1
         self.lanes[i] = _Lane()
 
-    def _step_chunks(self, g: int, params) -> int:
-        """One [slots, T] prefill-chunk dispatch for generation g's
-        prefilling lanes (fixed shapes: compiles once). Returns tokens
-        committed (first tokens of lanes that finished their prompt)."""
+    def _compile_chunk_widths(self, params):
+        """Compile the chunk program at every bucket width, before the
+        first chunk dispatch: one dispatch per width of padding rows
+        alone, which write no lane, so no width compiles later
+        (`prefill_cache_misses` counts any compile beyond one a width).
+        The state first moves to the params' device if they are committed
+        to one, as every dispatch's outputs are: a compiled program is
+        keyed by its arguments' placement too."""
         slots, T = self.ecfg.max_slots, self.ecfg.prefill_chunk
-        pre = np.array([ln.active and ln.gen == g and ln.prefilling
-                        for ln in self.lanes])
-        if not pre.any():
+        leaf = jax.tree.leaves(params)[0]
+        if getattr(leaf, "committed", False):
+            self._caches, self._pools, self._tokens = jax.device_put(
+                (self._caches, self._pools, self._tokens), leaf.sharding)
+        for w in self._chunk_widths:
+            off = jnp.zeros((w,), bool)
+            _, self._caches, self._pools = self._chunk_fn(
+                params, self._caches, self._pools, self._tokens,
+                jnp.zeros((w, T), jnp.int32), jnp.zeros((w,), jnp.int32),
+                off, off, jnp.full((w,), slots, jnp.int32), self._key)
+        self._chunks_compiled = True
+
+    def _step_chunks(self, g: int, params) -> int:
+        """One [P, T] prefill-chunk dispatch for generation g's prefilling
+        lanes, P the smallest bucket width that holds them (every width
+        compiles at the first dispatch). Returns tokens committed (first
+        tokens of lanes that finished their prompt)."""
+        slots, T = self.ecfg.max_slots, self.ecfg.prefill_chunk
+        pre = [i for i, ln in enumerate(self.lanes)
+               if ln.active and ln.gen == g and ln.prefilling]
+        if not pre:
             return 0
+        width = next(w for w in self._chunk_widths if w >= len(pre))
         with span("serve.chunk"):
+            if not self._chunks_compiled:
+                self._compile_chunk_widths(params)
             with span("serve.chunk.prep"):
-                chunks = np.zeros((slots, T), np.int32)
-                nv = np.zeros((slots,), np.int32)
-                fin = np.zeros((slots,), bool)
-                for i, ln in enumerate(self.lanes):
-                    if pre[i]:
-                        L = ln.prompt.shape[0]
-                        n = min(T, L - ln.pos)
-                        chunks[i, :n] = ln.prompt[ln.pos:ln.pos + n]
-                        nv[i], fin[i] = n, ln.pos + n >= L
+                chunks = np.zeros((width, T), np.int32)
+                nv = np.zeros((width,), np.int32)
+                fin = np.zeros((width,), bool)
+                lanes = np.full((width,), slots, np.int32)
+                for r, i in enumerate(pre):
+                    ln = self.lanes[i]
+                    L = ln.prompt.shape[0]
+                    n = min(T, L - ln.pos)
+                    chunks[r, :n] = ln.prompt[ln.pos:ln.pos + n]
+                    nv[r], fin[r], lanes[r] = n, ln.pos + n >= L, i
+                commit = lanes < slots
                 valid = int(nv.sum())
-                self.metrics.record_chunk(slots * T, valid)
-            with span("serve.chunk.dispatch", lanes=slots,
-                      lanes_valid=int(pre.sum()), tokens_valid=valid,
-                      tokens_computed=slots * T, finished=int(fin.sum())):
+                self.metrics.record_chunk(width, width * T, valid)
+            with span("serve.chunk.dispatch", lanes=width,
+                      lanes_valid=len(pre), tokens_valid=valid,
+                      tokens_computed=width * T, finished=int(fin.sum())):
                 self._key, sub = jax.random.split(self._key)
                 toks, self._caches, self._pools = self._chunk_fn(
                     params, self._caches, self._pools, self._tokens,
-                    jnp.asarray(chunks), jnp.asarray(nv), jnp.asarray(pre),
-                    jnp.asarray(fin), sub)
+                    jnp.asarray(chunks), jnp.asarray(nv),
+                    jnp.asarray(commit), jnp.asarray(fin),
+                    jnp.asarray(lanes), sub)
                 self._tokens = toks
             with span("serve.chunk.sync"):
                 toks_np = np.asarray(toks) if fin.any() else None
             with span("serve.chunk.harvest"):
                 committed = 0
                 t_now = time.time()
-                for i, ln in enumerate(self.lanes):
-                    if not pre[i]:
-                        continue
-                    ln.pos += int(nv[i])
-                    if fin[i]:
+                for r, i in enumerate(pre):
+                    ln = self.lanes[i]
+                    ln.pos += int(nv[r])
+                    if fin[r]:
                         ln.prefilling = False
                         ln.tokens = [int(toks_np[i, 0])]
                         ln.remaining = ln.budget - 1
@@ -614,7 +668,8 @@ class ServeEngine:
                 0, self._decode._cache_size() - 1)
             if self.ecfg.prefill_chunk > 0:
                 self.metrics.prefill_cache_misses = max(
-                    0, self._chunk_fn._cache_size() - 1)
+                    0, self._chunk_fn._cache_size() -
+                    len(self._chunk_widths))
         return committed
 
     def drain(self, max_steps: int = 10_000):

@@ -18,9 +18,10 @@ Collected host-side by the engine, zero device traffic:
 * paged-KV pool    — pages in use (peak), admissions deferred on pool
   exhaustion, and pool vs dense-bank device bytes (serve/paged.py);
 * lane use         — decode lanes committed over lanes computed, and
-  chunk tokens valid over tokens computed: every dispatch computes every
-  slot, so these say how much of it is work (the same numbers as the
-  `serve.*.dispatch` spans' stats, engine.py);
+  chunk tokens valid over tokens computed: a decode dispatch computes
+  every slot and a chunk dispatch its bucket's width, so these say how
+  much of it is work (the same numbers as the `serve.*.dispatch` spans'
+  stats, engine.py), with the chunk dispatches counted per bucket width;
 * freshness        — time-to-fresh-model: checkpoint-lands (the source's
   ``t_landed``) -> first token COMMITTED from a sequence admitted under
   that generation. The serving-side half of the paper's asynchrony story:
@@ -59,11 +60,12 @@ class ServeMetrics:
     decode_cache_misses: int = 0        # must stay 0 after warmup
     prefill_cache_misses: int = 0       # chunked prefill: must stay 0 too
     swaps_adopted: int = 0
-    # every dispatch computes every lane; these count how much commits
+    # what the dispatches compute, and how much of it commits
     decode_lanes_computed: int = 0
     decode_lanes_committed: int = 0
-    chunk_tokens_computed: int = 0      # slots x chunk length
+    chunk_tokens_computed: int = 0      # bucket width x chunk length
     chunk_tokens_valid: int = 0         # prompt tokens in the chunks
+    chunk_dispatches_by_width: Dict[int, int] = field(default_factory=dict)
     # paged KV pool (all 0 when the engine runs dense)
     pool_deferrals: int = 0             # admissions deferred: no pages
     pool_pages_peak: int = 0
@@ -82,7 +84,9 @@ class ServeMetrics:
         self.decode_lanes_computed += lanes
         self.decode_lanes_committed += committed
 
-    def record_chunk(self, computed: int, valid: int):
+    def record_chunk(self, width: int, computed: int, valid: int):
+        self.chunk_dispatches_by_width[width] = \
+            self.chunk_dispatches_by_width.get(width, 0) + 1
         self.chunk_tokens_computed += computed
         self.chunk_tokens_valid += valid
 
@@ -154,6 +158,8 @@ class ServeMetrics:
                                       self.decode_lanes_computed),
             "chunk_fill": _share(self.chunk_tokens_valid,
                                  self.chunk_tokens_computed),
+            "chunk_dispatches_by_width": dict(
+                sorted(self.chunk_dispatches_by_width.items())),
             "time_to_fresh_s": [round(x, 4) for x in fresh],
             "time_to_fresh_max_s": round(max(fresh), 4) if fresh else None,
         }

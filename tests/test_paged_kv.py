@@ -270,3 +270,79 @@ def test_paged_pool_smaller_than_dense_bank_at_half_occupancy():
     eng = ServeEngine(cfg, ecfg, params=_params(cfg))
     s = eng.metrics.summary()
     assert 0 < s["kv_bytes"] < s["kv_dense_bytes"]
+
+
+def _lane_state(eng, lane):
+    """Host copies of one lane's bank leaves and, paged, of its pool rows
+    (the bank and pools are donated: read them between dispatches)."""
+    bank = [np.asarray(x)[lane] for x in jax.tree.leaves(eng._caches)]
+    if eng._pools is None:
+        return bank
+    pages = np.asarray(eng._caches["pages"])[lane]
+    pages = pages[pages >= 0]
+    return bank + [np.asarray(p)[..., pages, :, :, :]
+                   for p in jax.tree.leaves(eng._pools)]
+
+
+@pytest.mark.parametrize("paged", [False, True], ids=["dense", "paged"])
+@pytest.mark.parametrize("arch", ["mamba2-780m", "olmo-1b"])
+def test_bucketed_chunks_match_blocking_and_compile_once(arch, paged):
+    """Staggered submissions put 1, 2, 3 and 5 of 8 lanes in prefill at
+    once, so the chunk dispatch runs at every bucket width (1, 2, 4, 8).
+    Greedy tokens equal the blocking oracle's; the first chunk step
+    compiles each width once and nothing compiles after the warm-up
+    request; a chunk dispatch with a padding row leaves every lane
+    outside its bucket, decoding or free, bitwise as it was."""
+    cfg = _cfg(arch)
+    lens = [13, 16, 8, 14, 11]
+    prompts = _ragged_prompts(cfg, lens, seed=3)
+    kw = dict(max_slots=8, prompt_len=16, max_new_tokens=4, queue_depth=16)
+    params = _params(cfg)
+    oracle = ServeEngine(cfg, EngineConfig(paged=False, **kw), params=params)
+    for i, p in enumerate(prompts):
+        assert oracle.submit(Request(i, p))
+    oracle.drain()
+
+    eng = ServeEngine(cfg, EngineConfig(prefill_chunk=4, paged=paged,
+                                        page_size=4, **kw), params=params)
+    compiles = []
+
+    def listener(event, duration, fun_name="", **_):
+        if event == "/jax/core/compile/backend_compile_duration":
+            compiles.append(fun_name)
+    jax.monitoring.register_event_duration_secs_listener(listener)
+    try:
+        # a warm-up request compiles every program the schedule uses
+        assert eng.submit(Request(-1, prompts[0][:6]))
+        eng.step()
+        assert compiles.count("jit(chunk_masked)") == 4   # one per width
+        eng.drain()
+        compiles.clear()
+        # one more request per step, two at the fourth: 1, 2, 3, 5 lanes
+        for rids in [[0], [1], [2], [3, 4]]:
+            for i in rids:
+                assert eng.submit(Request(i, prompts[i]))
+            eng.step()
+        # requests 0 and 2 finished their prompts at the fourth step and
+        # decode; the next chunk dispatch holds requests 1, 3 and 4 and a
+        # padding row, and no other lane
+        pre = [ln.rid for ln in eng.lanes if ln.prefilling]
+        assert sorted(pre) == [1, 3, 4]
+        outside = [i for i, ln in enumerate(eng.lanes) if not ln.prefilling]
+        before = [_lane_state(eng, i) for i in outside]
+        g = eng.adopted_gen
+        eng._step_chunks(g, eng.live[g])
+        for i, was in zip(outside, before):
+            for want, got in zip(was, _lane_state(eng, i)):
+                np.testing.assert_array_equal(got, want)
+        eng.drain()
+    finally:
+        jax.monitoring.unregister_event_duration_listener(listener)
+    assert compiles == []
+    del eng.completions[0]                              # the warm-up
+    assert _tokens(eng) == _tokens(oracle)
+    s = eng.metrics.summary()
+    assert s["chunk_dispatches_by_width"].keys() == {1, 2, 4, 8}
+    assert all(n > 0 for n in s["chunk_dispatches_by_width"].values())
+    assert s["prefill_cache_misses"] == 0
+    assert s["decode_cache_misses"] == 0

@@ -176,9 +176,10 @@ def test_local_steps_compile_compact(one_chip, wmt_nodes, monkeypatch):
 
 
 # The paged serving engine's two per-step programs at a small paged
-# configuration with the real head width (128) and a page of 16 rows:
-# the new KV rows are written into the donated page pools in place, so
-# no pool-sized buffer is copied, selected or contracted.
+# configuration with the real head width (128) and a page of 16 rows, the
+# chunk program at every bucket width: the new KV rows are written into
+# the donated page pools in place, so no pool-sized buffer is copied,
+# selected or contracted, and a narrower chunk computes less.
 @pytest.mark.parametrize("n_layers,pattern", [(2, 1), (5, 2)],
                          ids=["blocks", "blocks+tail"])
 def test_paged_engine_writes_pools_in_place(one_chip, n_layers, pattern):
@@ -211,11 +212,17 @@ def test_paged_engine_writes_pools_in_place(one_chip, n_layers, pattern):
         lambda: init_params(jax.random.PRNGKey(0), cfg)))
     key = specs(jax.eval_shape(lambda: jax.random.PRNGKey(0)))
     i32 = lambda *s: _spec(s, jnp.int32, one_chip)
-    flag = _spec((S,), jnp.bool_, one_chip)
+    flag = lambda w: _spec((w,), jnp.bool_, one_chip)
     head = (params, specs(eng._caches), specs(eng._pools), i32(S, 1))
-    for fn, rest in [(eng._decode, (flag, key)),
-                     (eng._chunk_fn, (i32(S, T), i32(S), flag, flag, key))]:
+    assert eng._chunk_widths == [1, 2, 4]
+    programs = [(eng._decode, (flag(S), key))] + [
+        (eng._chunk_fn, (i32(w, T), i32(w), flag(w), flag(w), i32(w), key))
+        for w in eng._chunk_widths]
+    flops = {}
+    for fn, rest in programs:
         c = fn.lower(*head, *rest).compile()
+        if fn is eng._chunk_fn:
+            flops[rest[0].shape[0]] = c.cost_analysis()["flops"]
         assert c.memory_analysis().alias_size_in_bytes >= pool_bytes
         for line in c.as_text().splitlines():
             m = re.match(r"\s*(?:ROOT )?%\S+ = \w+(\[[\d,]*\])\S* "
@@ -226,3 +233,5 @@ def test_paged_engine_writes_pools_in_place(one_chip, n_layers, pattern):
             assert shape not in shapes, f"pool-sized {op}: {line[:160]}"
             n = math.prod(int(d) for d in shape[1:-1].split(",") if d)
             assert op != "convolution" or n not in sizes, line[:160]
+    # the chunk's matrix products scale with the bucket's width
+    assert 0 < flops[1] <= 0.4 * flops[4], flops
